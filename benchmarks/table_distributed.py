@@ -57,7 +57,6 @@ os.environ["XLA_FLAGS"] = _f
 import numpy as np                                     # noqa: E402
 import jax                                             # noqa: E402
 import jax.numpy as jnp                                # noqa: E402
-from jax.experimental.shard_map import shard_map       # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P      # noqa: E402
 
 import repro.ff as ff                                  # noqa: E402

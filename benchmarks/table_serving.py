@@ -206,9 +206,12 @@ def _logprob_accuracy(params, cfg, reqs, cache_len) -> Dict:
         s_ff = token_logprob_ff(logits, tok)
         s32 = token_logprob(logits, tok)
         lg64 = np.asarray(logits, np.float64)
-        m = lg64.max(-1, keepdims=True)
-        lse = np.log(np.exp(lg64 - m).sum(-1)) + m[:, 0]
-        ref = lg64[np.arange(lg64.shape[0]), np.asarray(tok)] - lse
+        rows = np.arange(lg64.shape[0])
+        m = lg64.max(-1)
+        # log1p of the non-argmax terms: f64 log(1 + r) would round 1 + r
+        e = np.exp(lg64 - m[:, None])
+        e[rows, lg64.argmax(-1)] = 0.0
+        ref = (lg64[rows, np.asarray(tok)] - m) - np.log1p(e.sum(-1))
         got = np.asarray(s_ff.hi, np.float64) + np.asarray(s_ff.lo, np.float64)
         den = np.maximum(np.abs(ref), 1e-30)
         worst_ff = max(worst_ff, float(np.max(np.abs(got - ref) / den)))

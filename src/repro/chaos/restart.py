@@ -10,13 +10,17 @@ full scenario per kv_mode:
   2. the PARENT waits for snapshot progress, SIGKILLs the child — which
      may die mid-snapshot-write (torn ``.tmp``) or mid-journal-append
      (torn JSONL tail); both are designed-for states;
-  3. the parent resumes via :func:`repro.serve.resume_engine` (newest
-     VERIFIED snapshot generation + WAL replay) and runs to completion;
-  4. every request's tokens must be **identical** — and the FF logprob
-     limb pairs **bit-for-bit identical** — to an uninterrupted engine
-     run of the same request set (greedy decode is deterministic, and
-     both processes compile the same XLA programs under the pinned
-     ``--xla_cpu_max_isa`` ISA).
+  3. a second child (``--verify``) resumes via
+     :func:`repro.serve.resume_engine` (newest VERIFIED snapshot
+     generation + WAL replay) and runs to completion;
+  4. in that child every request's tokens must be **identical** — and
+     the FF logprob limb pairs **bit-for-bit identical** — to an
+     uninterrupted engine run of the same request set (greedy decode is
+     deterministic, and both processes compile the same XLA programs
+     under the pinned ``--xla_cpu_max_isa`` ISA).
+
+The parent never imports JAX: on an accelerator a device belongs to one
+process at a time, so each child is the only process holding it.
 
 Exit 0 iff every scenario ends in exact-replay parity with every request
 in a documented terminal status.
@@ -100,24 +104,62 @@ def child_main(workdir: str, kv_mode: str, step_delay: float) -> int:
     return 0
 
 
-def run_scenario(workdir: str, kv_mode: str = "bf16", *,
-                 step_delay: float = 0.25, kill_after_snaps: int = 2,
-                 timeout_s: float = 300.0) -> dict:
-    """Parent side: spawn, SIGKILL mid-decode, resume, verify parity.
-    Returns a report dict; raises AssertionError on any contract
-    violation."""
-    os.makedirs(workdir, exist_ok=True)
-    progress = os.path.join(workdir, "progress.json")
+def verify_main(workdir: str, kv_mode: str) -> dict:
+    """Resume the killed engine, run it to completion, and check
+    exact-replay parity against an uninterrupted run.  Returns a report
+    dict; raises AssertionError on any contract violation."""
+    cfg = _cfg()
+    params = _params(cfg)
+    from repro.serve import OK, resume_engine
+    eng = resume_engine(params, cfg, os.path.join(workdir, "snap"),
+                        journal=os.path.join(workdir, "wal.jsonl"))
+    resumed = eng.run()
+
+    base = _engine(params, cfg, kv_mode)
+    for r in _requests():
+        base.submit(r)
+    baseline = base.run()
+
+    assert set(resumed) == set(baseline), (
+        f"[{kv_mode}] uid sets differ: resumed {sorted(resumed)} vs "
+        f"baseline {sorted(baseline)}")
+    for uid in sorted(baseline):
+        a, b = baseline[uid], resumed[uid]
+        assert b.status == OK, (
+            f"[{kv_mode}] uid {uid}: resumed status {b.status} "
+            f"({b.detail})")
+        assert np.array_equal(a.tokens, b.tokens), (
+            f"[{kv_mode}] uid {uid}: token mismatch after resume")
+        assert np.array_equal(a.logprobs_ff, b.logprobs_ff), (
+            f"[{kv_mode}] uid {uid}: FF logprob limbs not bit-identical")
+    return {"resumed_uids": sorted(resumed),
+            "statuses": {u: resumed[u].status for u in sorted(resumed)}}
+
+
+def _child(mode_flag: str, workdir: str, kv_mode: str, *extra: str,
+           **popen_kw) -> subprocess.Popen:
     env = dict(os.environ)
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env["PYTHONPATH"] = src_root + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "repro.chaos.restart", "--child",
-         "--dir", workdir, "--kv-mode", kv_mode,
-         "--step-delay", str(step_delay)],
-        env=env, stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+    return subprocess.Popen(
+        [sys.executable, "-m", "repro.chaos.restart", mode_flag,
+         "--dir", workdir, "--kv-mode", kv_mode, *extra], env=env,
+        **popen_kw)
+
+
+def run_scenario(workdir: str, kv_mode: str = "bf16", *,
+                 step_delay: float = 0.25, kill_after_snaps: int = 2,
+                 timeout_s: float = 300.0) -> dict:
+    """Parent side: spawn, SIGKILL mid-decode, resume and verify parity
+    in a second child.  Returns a report dict; raises AssertionError on
+    any contract violation."""
+    os.makedirs(workdir, exist_ok=True)
+    progress = os.path.join(workdir, "progress.json")
+    proc = _child("--child", workdir, kv_mode,
+                  "--step-delay", str(step_delay),
+                  stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
     deadline = time.monotonic() + timeout_s
     try:
         while True:
@@ -144,38 +186,33 @@ def run_scenario(workdir: str, kv_mode: str = "bf16", *,
     assert not os.path.exists(os.path.join(workdir, "done")), \
         f"[{kv_mode}] child finished cleanly; the kill tested nothing"
 
-    cfg = _cfg()
-    params = _params(cfg)
-    from repro.serve import OK, resume_engine
-    eng = resume_engine(params, cfg, os.path.join(workdir, "snap"),
-                        journal=os.path.join(workdir, "wal.jsonl"))
-    resumed = eng.run()
-
-    base = _engine(params, cfg, kv_mode)
-    for r in _requests():
-        base.submit(r)
-    baseline = base.run()
-
-    assert set(resumed) == set(baseline), (
-        f"[{kv_mode}] uid sets differ: resumed {sorted(resumed)} vs "
-        f"baseline {sorted(baseline)}")
-    for uid in sorted(baseline):
-        a, b = baseline[uid], resumed[uid]
-        assert b.status == OK, (
-            f"[{kv_mode}] uid {uid}: resumed status {b.status} "
-            f"({b.detail})")
-        assert np.array_equal(a.tokens, b.tokens), (
-            f"[{kv_mode}] uid {uid}: token mismatch after resume")
-        assert np.array_equal(a.logprobs_ff, b.logprobs_ff), (
-            f"[{kv_mode}] uid {uid}: FF logprob limbs not bit-identical")
+    verifier = _child("--verify", workdir, kv_mode,
+                      stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
+                      text=True)
+    try:
+        _, err = verifier.communicate(timeout=timeout_s)
+    finally:
+        if verifier.poll() is None:
+            verifier.kill()
+            verifier.wait(timeout=60)
+    path = os.path.join(workdir, "report.json")
+    assert os.path.exists(path), (
+        f"[{kv_mode}] verify child wrote no report "
+        f"(rc={verifier.returncode}): {err[-3000:]}")
+    with open(path) as f:
+        report = json.load(f)
+    if "error" in report:
+        raise AssertionError(report["error"])
+    assert verifier.returncode == 0, err[-3000:]
     return {"kv_mode": kv_mode, "killed_at_snaps": kill_after_snaps,
-            "resumed_uids": sorted(resumed),
-            "statuses": {u: resumed[u].status for u in sorted(resumed)}}
+            **report}
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--child", action="store_true")
+    ap.add_argument("--verify", action="store_true",
+                    help="resume a killed --child run and check parity")
     ap.add_argument("--dir", type=str, default=None)
     ap.add_argument("--kv-mode", type=str, default="bf16",
                     choices=KV_MODES)
@@ -187,6 +224,16 @@ def main(argv=None) -> int:
         if not args.dir:
             ap.error("--child requires --dir")
         return child_main(args.dir, args.kv_mode, args.step_delay)
+    if args.verify:
+        if not args.dir:
+            ap.error("--verify requires --dir")
+        try:
+            report = verify_main(args.dir, args.kv_mode)
+        except AssertionError as e:
+            report = {"error": str(e)}
+        with open(os.path.join(args.dir, "report.json"), "w") as f:
+            json.dump(report, f)
+        return 1 if "error" in report else 0
     import tempfile
     failures = []
     for mode in args.modes.split(","):

@@ -430,7 +430,7 @@ def matmul_f64(a: Array, b: Array) -> FF:
     at ~2^-48 relative — comfortably inside the accurate tier at a small
     multiple of the naive f32 GEMM (vs ~10x+ for the best pure-f32 scheme).
 
-    ``jax.experimental.enable_x64`` scopes the wide-dtype escape to this
+    ``jax.enable_x64`` scopes the wide-dtype escape to this
     trace only: it works eagerly, inside an outer f32 ``jit``, and under
     ``vmap``/``grad``, without flipping the global x64 flag.  The body
     lives behind its own ``jit`` boundary on purpose: ``custom_vjp``'s
@@ -446,7 +446,7 @@ def matmul_f64(a: Array, b: Array) -> FF:
 
 @jax.jit
 def _matmul_f64_jit(a: Array, b: Array) -> Tuple[Array, Array]:
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         r = lax.dot(lax.convert_element_type(a, jnp.float64),
                     lax.convert_element_type(b, jnp.float64),
                     precision=lax.Precision.HIGHEST)

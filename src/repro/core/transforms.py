@@ -185,7 +185,7 @@ def pairwise_sum_compensated(p: Array, axis: int, err: Array = None,
     """
     ts = two_sum_fn if two_sum_fn is not None else two_sum
     if err is None:
-        err = jnp.zeros_like(jnp.take(p, 0, axis=axis))
+        err = jnp.zeros_like(lax.index_in_dim(p, 0, axis, keepdims=False))
     while p.shape[axis] > 1:
         width = p.shape[axis]
         half = width // 2
@@ -198,4 +198,4 @@ def pairwise_sum_compensated(p: Array, axis: int, err: Array = None,
                 [s, lax.slice_in_dim(p, width - 1, width, axis=axis)],
                 axis=axis)
         p = s
-    return jnp.take(p, 0, axis=axis), err
+    return lax.index_in_dim(p, 0, axis, keepdims=False), err

@@ -45,6 +45,7 @@ from repro.ff.scope import (  # noqa: F401
 )
 from repro.ff.dispatch import (  # noqa: F401
     backend, register, ops, impls, resolve_name, resolve_opts, mesh_default,
+    FFFallbackWarning,
 )
 from repro.ff.tuning import tune  # noqa: F401
 from repro.ff import tuning  # noqa: F401

@@ -42,6 +42,15 @@ Array = jnp.ndarray
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 _DEFAULTS: Dict[str, Dict[str, str]] = {}     # op -> {backend|"*": impl}
 _MESH_DEFAULTS: Dict[str, str] = {}           # op -> impl inside ff.on_mesh
+# (op, impl) pairs that hold a whole row in VMEM: as a backend default, a
+# row past MAX_FUSED_COLS yields to the generic "*" default at resolution
+# (named in telemetry) instead of falling back inside the call
+_WHOLE_ROW: set = set()
+
+
+class FFFallbackWarning(UserWarning):
+    """A kernel impl ran its jnp formulation instead of the kernel."""
+
 
 # static fallback order for a "tuned_accurate" request on an untuned shape
 # bucket (see resolve_name): per-op, first registered name wins
@@ -69,7 +78,8 @@ def backend() -> str:
 
 def register(op: str, impl: str, fn: Callable, *,
              default_for: Tuple[str, ...] = (),
-             mesh_default: bool = False) -> Callable:
+             mesh_default: bool = False,
+             whole_row: bool = False) -> Callable:
     """Register ``fn`` as implementation ``impl`` of ``op``.
 
     ``default_for`` lists backends this impl is the default on ("*" = any
@@ -77,12 +87,17 @@ def register(op: str, impl: str, fn: Callable, *,
     it the default *inside an* ``ff.on_mesh`` *scope* (mesh-context
     resolution; see module docstring) — outside any mesh scope it is only
     reachable by explicit ``impl=``/``ff.use`` selection.
+    ``whole_row=True`` marks a kernel that holds a whole last-axis row:
+    as a backend default it yields rows that do not fit to the generic
+    ``"*"`` default.
     """
     _REGISTRY.setdefault(op, {})[impl] = fn
     for b in default_for:
         _DEFAULTS.setdefault(op, {})[b] = impl
     if mesh_default:
         _MESH_DEFAULTS[op] = impl
+    if whole_row:
+        _WHOLE_ROW.add((op, impl))
     return fn
 
 
@@ -166,6 +181,9 @@ def resolve_name(op: str, impl: Optional[str] = None,
         d = _DEFAULTS.get(op, {})
         name = d.get(backend(), d.get("*"))
         src = "static_default"
+        if (shape is not None and (op, name) in _WHOLE_ROW
+                and not _row_fits(shape)):
+            name, src = d.get("*"), "shape_default"
     if name is None:
         name, src = next(iter(_REGISTRY[op])), "first_registered"
     if name not in _REGISTRY[op]:
@@ -237,10 +255,11 @@ def _fallback_warn(impl: str, op: str, why: str) -> None:
     """A kernel impl substituting its jnp formulation must say so: tuned
     winners/defaults must never brick a call, but an EXPLICIT impl=
     request landing here would otherwise silently validate or benchmark
-    the wrong kernel.  Fires once per trace (Python-level warn)."""
+    the wrong kernel.  Fires once per trace (Python-level warn), as an
+    :class:`FFFallbackWarning` so a caller can make it an error."""
     import warnings
     warnings.warn(f"ff.{op}(impl={impl!r}): {why}; falling back to the "
-                  f"jnp formulation", stacklevel=3)
+                  f"jnp formulation", FFFallbackWarning, stacklevel=3)
 
 
 def _as_ff(x) -> FF:
@@ -514,12 +533,17 @@ def _logsumexp_jnp(x: Array, axis: int = -1, *, block: int = 256, **_kw):
     return jnp.squeeze(m, axis=axis) + jnp.log(s.to_f32())
 
 
+def _row_fits(shape: Tuple[int, ...]) -> bool:
+    """Whether a row of ``shape`` (last axis) fits the whole-row composite
+    kernels' VMEM budget (see ff_fused.MAX_FUSED_COLS)."""
+    from repro.kernels import ff_fused
+    return shape[-1] <= ff_fused.MAX_FUSED_COLS
+
+
 def _last_axis_fusable(x: Array, axis: int) -> bool:
     """Whether the whole-row composite kernels apply: last-axis reduction
-    with the row fitting the VMEM budget (see ff_fused.MAX_FUSED_COLS)."""
-    from repro.kernels import ff_fused
-    return (x.ndim >= 1 and axis in (-1, x.ndim - 1)
-            and x.shape[-1] <= ff_fused.MAX_FUSED_COLS)
+    with the row fitting the VMEM budget."""
+    return x.ndim >= 1 and axis in (-1, x.ndim - 1) and _row_fits(x.shape)
 
 
 def _logsumexp_pallas(x: Array, axis: int = -1, *, br: int = 256,
@@ -549,10 +573,9 @@ def _sum_f64_axis(e: Array, axis: int) -> Array:
     jit boundary; see its docstring for why the boundary is load-bearing
     — and module-level like it, so eager callers hit the jit cache
     instead of recompiling per call)."""
-    import jax.experimental
     from jax import lax
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         s = jnp.sum(lax.convert_element_type(e, jnp.float64), axis=axis)
         return lax.convert_element_type(s, jnp.float32)
 
@@ -592,7 +615,8 @@ register("mean", "jnp", _mean_jnp, default_for=("*",))
 # native-f64 reduction where the hardware has f64 units (CPU) — the old
 # blanket default_for=("*",) left every non-jnp path dead code
 register("logsumexp", "jnp", _logsumexp_jnp, default_for=("*",))
-register("logsumexp", "pallas", _logsumexp_pallas, default_for=("tpu",))
+register("logsumexp", "pallas", _logsumexp_pallas, default_for=("tpu",),
+         whole_row=True)
 register("logsumexp", "f64", _logsumexp_f64, default_for=("cpu",))
 
 
@@ -626,7 +650,8 @@ def _softmax_pallas(x: Array, axis: int = -1, *, br: int = 256,
 
 
 register("softmax", "jnp", _softmax_jnp, default_for=("*",))
-register("softmax", "pallas", _softmax_pallas, default_for=("tpu",))
+register("softmax", "pallas", _softmax_pallas, default_for=("tpu",),
+         whole_row=True)
 register("softmax", "f64", _softmax_f64, default_for=("cpu",))
 
 
@@ -685,7 +710,8 @@ def _mean_sq_fused(x: Array, *, interpret: Optional[bool] = None,
 
 
 register("mean_sq", "jnp", _mean_sq_jnp, default_for=("*",))
-register("mean_sq", "fused", _mean_sq_fused, default_for=("tpu",))
+register("mean_sq", "fused", _mean_sq_fused, default_for=("tpu",),
+         whole_row=True)
 
 
 def _norm_stats_jnp(x: Array, *, block: int = 128, **_kw):
@@ -709,7 +735,8 @@ def _norm_stats_pallas(x: Array, *, br: int = 256,
 
 
 register("norm_stats", "jnp", _norm_stats_jnp, default_for=("*",))
-register("norm_stats", "pallas", _norm_stats_pallas, default_for=("tpu",))
+register("norm_stats", "pallas", _norm_stats_pallas,
+         default_for=("tpu",), whole_row=True)
 
 
 # -- FF elementary functions (the ff.math subsystem) -------------------------
@@ -797,10 +824,9 @@ def _math_f64_jit(op: str, ah: Array, al: Array) -> Tuple[Array, Array]:
     transcendentals).  Same trace-scoped enable_x64 behind a module-level
     nested-jit boundary (see ``ffmatmul._matmul_f64_jit`` for why the
     boundary is load-bearing under custom_vjp lowering)."""
-    import jax.experimental
     from jax import lax
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x = (lax.convert_element_type(ah, jnp.float64)
              + lax.convert_element_type(al, jnp.float64))
         r = _math_f64_fns()[op](x)
@@ -863,7 +889,6 @@ def _pow_pallas(a, b, *, block=None, interpret: Optional[bool] = None,
 
 @jax.jit
 def _pow_f64_jit(ah, al, bh, bl) -> Tuple[Array, Array]:
-    import jax.experimental
     from jax import lax
 
     # domain test on the f32 limb (a < 0 iff hi < 0 for normalized FF):
@@ -871,7 +896,7 @@ def _pow_f64_jit(ah, al, bh, bl) -> Tuple[Array, Array]:
     # b == 0 is excluded: pow22's rule is b == 0 -> 1 LAST (0**0 == 1,
     # (-2)**0 == 1), and the mask must not flip that between impl tiers
     neg = (ah < jnp.float32(0)) & (bh != jnp.float32(0))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         a = (lax.convert_element_type(ah, jnp.float64)
              + lax.convert_element_type(al, jnp.float64))
         b = (lax.convert_element_type(bh, jnp.float64)

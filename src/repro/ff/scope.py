@@ -138,7 +138,7 @@ class on_mesh:
     completely untouched — mesh routing is a scoped opt-in, exactly like
     :class:`policy` / :class:`use`::
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = repro.launch.mesh.make_mesh((8,), ("data",))   # Auto axes
         with ff.on_mesh(mesh, axis="data"):
             C = ff.matmul(A, B)                    # K split over "data"
             C = ff.matmul(A, B, impl="sharded_accurate")   # ppermute tree

@@ -5,7 +5,7 @@ The paper's float-float operators survive a device mesh only if the
 on-device arithmetic — ``psum``-ing FF partials as two independent f32
 planes silently reintroduces the naive-f32 rounding the whole technique
 exists to remove.  This module partitions the FF matmul/reduction ops over
-a mesh with ``jax.experimental.shard_map`` and combines partial results
+a mesh with ``jax.shard_map`` and combines partial results
 across devices with *compensated* collectives:
 
 ``combine="psum"`` (the fast class)
@@ -64,7 +64,6 @@ from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import ff as core_ff
@@ -213,10 +212,10 @@ def _mm_sharded(accurate: bool):
             r = _combine(r, axis, mesh, how)
             return r.hi, r.lo
 
-        hi, lo = shard_map(
+        hi, lo = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, axis), P(axis, None)),
-            out_specs=(P(), P()), check_rep=False)(
+            out_specs=(P(), P()), check_vma=False)(
                 jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32))
         return FF(hi, lo)
 
@@ -310,8 +309,8 @@ def _sum_sharded(x: Array, axis=None, *, combine: str = "tree",
         return r.hi, r.lo
 
     in_spec = P(maxis, *([None] * (x.ndim - 1)))
-    hi, lo = shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                       out_specs=(P(), P()), check_rep=False)(x)
+    hi, lo = jax.shard_map(body, mesh=mesh, in_specs=(in_spec,),
+                           out_specs=(P(), P()), check_vma=False)(x)
     return FF(hi, lo)
 
 
@@ -345,8 +344,8 @@ def _dot_sharded(a: Array, b: Array, axis=None, *, combine: str = "tree",
         return r.hi, r.lo
 
     in_spec = P(maxis, *([None] * (a.ndim - 1)))
-    hi, lo = shard_map(body, mesh=mesh, in_specs=(in_spec, in_spec),
-                       out_specs=(P(), P()), check_rep=False)(a, b)
+    hi, lo = jax.shard_map(body, mesh=mesh, in_specs=(in_spec, in_spec),
+                           out_specs=(P(), P()), check_vma=False)(a, b)
     return FF(hi, lo)
 
 
@@ -374,8 +373,9 @@ def _norm_stats_sharded(x: Array, **opts):
         return base(xl, **opts)
 
     in_spec = P(maxis, *([None] * (x.ndim - 1)))
-    return shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                     out_specs=(P(maxis), P(maxis)), check_rep=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=(in_spec,),
+                         out_specs=(P(maxis), P(maxis)),
+                         check_vma=False)(x)
 
 
 # ---------------------------------------------------------------------------

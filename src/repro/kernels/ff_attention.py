@@ -310,7 +310,7 @@ def flash_attention_ff(q: Array, k: Array, v: Array, *, causal: bool = True,
 # ===========================================================================
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, ol_ref,
-                 m_sc, dh_sc, dl_sc, nh_sc, nl_sc, *,
+                 m_sc, dh_sc, dl_sc, nh_sc, nl_sc, qT_sc, pT_sc, plT_sc, *,
                  nkv: int, bq: int, bkv: int, hdp: int,
                  Skv: int, causal: bool, q_offset: int, scale: float):
     i = pl.program_id(1)
@@ -324,19 +324,18 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, ol_ref,
         nh_sc[...] = jnp.zeros_like(nh_sc[...])
         nl_sc[...] = jnp.zeros_like(nl_sc[...])
 
-    qb = q_ref[0]                                     # (bq, hdp)
-    kbT = k_ref[0]                                    # (hdp, bkv)
-    vb = v_ref[0]                                     # (bkv, hdp)
-
     # FF scores: TwoProd-exact outer products per head-dim slice through a
-    # Neumaier cascade (k arrives pre-transposed so the slice is a native
-    # (1, bkv) row; the zero-padded hdp tail contributes exactly 0)
+    # Neumaier cascade (the zero-padded hdp tail contributes exactly 0).
+    # The loops index refs by row (``pl.ds`` on the sublane axis): k arrives
+    # pre-transposed, q is transposed into scratch, and a row comes back as
+    # a column with ``.T`` — Mosaic lowers no value-level dynamic_slice.
+    qT_sc[...] = q_ref[0].T                           # (hdp, bq)
     zs = jnp.zeros((bq, bkv), jnp.float32)
 
     def sbody(d, carry):
         s_, c_, cc_ = carry
-        qd = lax.dynamic_slice_in_dim(qb, d, 1, axis=1)       # (bq, 1)
-        kd = lax.dynamic_slice_in_dim(kbT, d, 1, axis=0)      # (1, bkv)
+        qd = qT_sc[pl.ds(d, 1), :].T                  # (bq, 1)
+        kd = k_ref[0, pl.ds(d, 1), :]                 # (1, bkv)
         th, tl = eft.two_prod(jnp.broadcast_to(qd, (bq, bkv)),
                               jnp.broadcast_to(kd, (bq, bkv)))
         s2, e = eft.two_sum(s_, th)
@@ -372,22 +371,24 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, ol_ref,
     z = jnp.zeros((bq, LANE), jnp.float32)
     sA, cA, ccA = _lane_cascade(ph, z, z, z, LANE)
     sA, cA, ccA = _lane_cascade(plo, sA, cA, ccA, LANE)
-    bs_h, bs_l = _fold_lanes(sA, cA, ccA)             # (bq,)
+    bs_h, bs_l = _fold_lanes(sA, cA, ccA)             # (bq, 1)
     d0h, d0l = eft.mul22(dh_sc[:, :1], dl_sc[:, :1], alh, all_)
-    d1h, d1l = eft.add22(d0h, d0l, bs_h[:, None], bs_l[:, None])
+    d1h, d1l = eft.add22(d0h, d0l, bs_h, bs_l)
     dh_sc[...] = jnp.broadcast_to(d1h, (bq, LANE))
     dl_sc[...] = jnp.broadcast_to(d1l, (bq, LANE))
 
     # numerator block sum: Neumaier cascade over the bkv terms, each an
     # exact TwoProd of the hi plane with the lo-plane product in the
-    # compensation stream
+    # compensation stream (p planes transposed into scratch, read by row)
+    pT_sc[...] = ph.T                                 # (bkv, bq)
+    plT_sc[...] = plo.T
     zn = jnp.zeros((bq, hdp), jnp.float32)
 
     def body(t, carry):
         s_, c_, cc_ = carry
-        pt_h = lax.dynamic_slice_in_dim(ph, t, 1, axis=1)     # (bq, 1)
-        pt_l = lax.dynamic_slice_in_dim(plo, t, 1, axis=1)
-        vt = lax.dynamic_slice_in_dim(vb, t, 1, axis=0)       # (1, hdp)
+        pt_h = pT_sc[pl.ds(t, 1), :].T                # (bq, 1)
+        pt_l = plT_sc[pl.ds(t, 1), :].T
+        vt = v_ref[0, pl.ds(t, 1), :]                 # (1, hdp)
         th, tl = eft.two_prod(jnp.broadcast_to(pt_h, (bq, hdp)),
                               jnp.broadcast_to(vt, (bq, hdp)))
         tl = tl + pt_l * vt
@@ -481,7 +482,10 @@ def flash_attention_pallas(q: Array, k: Array, v: Array, *,
                         pltpu.VMEM((bq, LANE), jnp.float32),
                         pltpu.VMEM((bq, LANE), jnp.float32),
                         pltpu.VMEM((bq, hdp), jnp.float32),
-                        pltpu.VMEM((bq, hdp), jnp.float32)],
+                        pltpu.VMEM((bq, hdp), jnp.float32),
+                        pltpu.VMEM((hdp, bq), jnp.float32),
+                        pltpu.VMEM((bkv, bq), jnp.float32),
+                        pltpu.VMEM((bkv, bq), jnp.float32)],
         interpret=interpret,
     )(q3, k3, v3)
 
@@ -510,12 +514,10 @@ def _attention_f64_jit(q: Array, k: Array, v: Array, kv_len: Array,
     the boundary is load-bearing); constants inside the scope are traced
     OPERANDS (the scale rides in as an f32 array — a literal would be
     canonicalized to f32 at trace time and poison the f64 multiply)."""
-    import jax.experimental
-
     B, Sq, H, hd = q.shape
     Skv, KV = k.shape[1], k.shape[2]
     G = H // KV
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         c64 = lambda x: lax.convert_element_type(x, jnp.float64)
         q64 = c64(jnp.asarray(q, jnp.float32)).reshape(B, Sq, KV, G, hd)
         k64 = c64(jnp.asarray(k, jnp.float32))

@@ -143,32 +143,28 @@ def _eval_instrs(prog, leaf_blocks):
 
 def _lane_cascade(val: Array, s, c, cc, lane: int):
     """One block's contribution to a lane-parallel Neumaier cascade:
-    fold (br, bc) into three (br, lane) accumulators."""
-    def body(t, carry):
-        si, ci, cci = carry
-        xt = lax.dynamic_slice_in_dim(val, t * lane, lane, axis=1)
-        s2, e = eft.two_sum(si, xt)
-        c2, e2 = eft.two_sum(ci, e)
-        return s2, c2, cci + e2
-
-    return lax.fori_loop(0, val.shape[1] // lane, body, (s, c, cc))
+    fold (br, bc) into three (br, lane) accumulators.  Static lane-aligned
+    slices (unrolled): Mosaic has no lowering for a value-level
+    ``dynamic_slice``."""
+    for t in range(val.shape[1] // lane):
+        s, e = eft.two_sum(s, val[:, t * lane:(t + 1) * lane])
+        c, e2 = eft.two_sum(c, e)
+        cc = cc + e2
+    return s, c, cc
 
 
 def _fold_lanes(s_acc, c_acc, cc_acc) -> Tuple[Array, Array]:
     """Exact sequential fold of the ``lane`` per-lane accumulators (same
-    scheme as ``ff_reduce``): (br, lane) x3 -> FF per row (br,)."""
-    def fold(i, carry):
-        fh, fl = carry
-        sh, sl = eft.two_sum(
-            fh, lax.dynamic_slice_in_dim(s_acc, i, 1, axis=1)[:, 0])
-        v = sl + (fl
-                  + lax.dynamic_slice_in_dim(c_acc, i, 1, axis=1)[:, 0]
-                  + lax.dynamic_slice_in_dim(cc_acc, i, 1, axis=1)[:, 0])
-        return eft.fast_two_sum(sh, v)
-
-    br = s_acc.shape[0]
-    z = jnp.zeros((br,), jnp.float32)
-    return lax.fori_loop(0, s_acc.shape[1], fold, (z, z))
+    scheme as ``ff_reduce``): (br, lane) x3 -> FF per row, (br, 1).
+    Runs on the transposed (lane, br) planes so each step reads one
+    sublane row instead of one lane column of every row tile."""
+    sT, cT, ccT = s_acc.T, c_acc.T, cc_acc.T
+    fh = fl = jnp.zeros((1, sT.shape[1]), jnp.float32)
+    for i in range(sT.shape[0]):
+        sh, sl = eft.two_sum(fh, sT[i:i + 1])
+        v = sl + (fl + cT[i:i + 1] + ccT[i:i + 1])
+        fh, fl = eft.fast_two_sum(sh, v)
+    return fh.T, fl.T
 
 
 def _unbroadcast(arr: Array, full_shape, nd) -> Array:
@@ -307,8 +303,8 @@ def run_pallas(prog, operands: Sequence, *,
                     fh, fl = _fold_lanes(sc[3 * red][...],
                                          sc[3 * red + 1][...],
                                          sc[3 * red + 2][...])
-                    oh_ref[...] = fh[:, None]
-                    ol_ref[...] = fl[:, None]
+                    oh_ref[...] = fh
+                    ol_ref[...] = fl
 
                 oref += 2
                 red += 1
@@ -386,20 +382,20 @@ def _softmax_kernel(x_ref, out_ref, *, C: int, mode: str, accurate: bool):
         s, c, cc = _lane_cascade(el, s, c, cc, LANE)
         fh, fl = _fold_lanes(s, c, cc)                 # FF row sum
         if mode == "softmax":
-            qh, _ql = eft.div22(eh, el, fh[:, None], fl[:, None])
+            qh, _ql = eft.div22(eh, el, fh, fl)
             out_ref[...] = qh
         else:
-            lh, ll = ffmath.log22(fh[:, None], fl[:, None], eft)
+            lh, ll = ffmath.log22(fh, fl, eft)
             oh, _ol = eft.add212(lh, ll, m)
             out_ref[...] = oh
         return
     e = jnp.where(mask, jnp.exp(x - m), jnp.float32(0))
     s, c, cc = _lane_cascade(e, z, z, z, LANE)
-    fh, _fl = _fold_lanes(s, c, cc)                    # (br,)
+    fh, _fl = _fold_lanes(s, c, cc)                    # (br, 1)
     if mode == "softmax":
-        out_ref[...] = e / fh[:, None]
+        out_ref[...] = e / fh
     else:                                              # logsumexp
-        out_ref[...] = m + jnp.log(fh)[:, None]
+        out_ref[...] = m + jnp.log(fh)
 
 
 @functools.partial(jax.jit,
@@ -457,12 +453,12 @@ def _norm_stats_kernel(x_ref, mu_ref, var_ref, *, C: int):
     z = jnp.zeros((x.shape[0], LANE), jnp.float32)
     s, c, cc = _lane_cascade(xz, z, z, z, LANE)
     s1h, _ = _fold_lanes(s, c, cc)
-    mu = s1h / jnp.float32(C)                          # (br,)
-    d = jnp.where(mask, x - mu[:, None], jnp.float32(0))
+    mu = s1h / jnp.float32(C)                          # (br, 1)
+    d = jnp.where(mask, x - mu, jnp.float32(0))
     s, c, cc = _lane_cascade(d * d, z, z, z, LANE)
     s2h, _ = _fold_lanes(s, c, cc)
-    mu_ref[...] = mu[:, None]
-    var_ref[...] = (s2h / jnp.float32(C))[:, None]
+    mu_ref[...] = mu
+    var_ref[...] = s2h / jnp.float32(C)
 
 
 @functools.partial(jax.jit, static_argnames=("br", "interpret"))

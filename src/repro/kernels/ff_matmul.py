@@ -255,22 +255,19 @@ def _ff_matmul_dot2_kernel(a_ref, b_ref, oh_ref, ol_ref, s_acc, c_acc, cc_acc,
 
     a = a_ref[...]          # (bm, bk)
     b = b_ref[...]          # (bk, bn)
-
-    def body(j, carry):
-        s, c, cc = carry
-        aj = lax.dynamic_slice_in_dim(a, j * vec, vec, axis=1)   # (bm, vec)
-        bj = lax.dynamic_slice_in_dim(b, j * vec, vec, axis=0)   # (vec, bn)
+    s, c, cc = s_acc[...], c_acc[...], cc_acc[...]
+    # static slabs, unrolled: Mosaic cannot lower a value dynamic_slice
+    for j in range(bk // vec):
+        aj = a[:, j * vec:(j + 1) * vec]                         # (bm, vec)
+        bj = b[j * vec:(j + 1) * vec, :]                         # (vec, bn)
         # batched Mul12: all vec outer products of this slab, exactly
         p, pe = eft.two_prod(aj[:, :, None], bj[None, :, :])     # (bm,vec,bn)
         # pairwise-compensated tree reduction over the slab axis
         slab, err = eft.pairwise_sum_compensated(
             p, axis=1, err=jnp.sum(pe, axis=1))
-        s2, se = eft.two_sum(s, slab)
-        c2, ce = eft.two_sum(c, se + err)
-        return s2, c2, cc + ce
-
-    s, c, cc = lax.fori_loop(
-        0, bk // vec, body, (s_acc[...], c_acc[...], cc_acc[...]))
+        s, se = eft.two_sum(s, slab)
+        c, ce = eft.two_sum(c, se + err)
+        cc = cc + ce
     s_acc[...] = s
     c_acc[...] = c
     cc_acc[...] = cc

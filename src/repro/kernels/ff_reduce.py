@@ -7,8 +7,9 @@ reductions when the precision policy requests ``ff_reductions``.
 
 Grid: (rows/br, cols/bc) with the column dimension innermost; the running
 (s, c, cc) cascade lives in VMEM scratch and persists across column steps.
-Inside a block the reduction is a fori_loop over lanes-groups so the order
-is deterministic (bit-reproducible across shardings of other dims).
+Inside a block the reduction walks the lane-groups in order (the same
+cascade as ``ff_fused``), so the order is deterministic (bit-reproducible
+across shardings of other dims).
 """
 
 from __future__ import annotations
@@ -18,17 +19,16 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import eft
+from repro.kernels.ff_fused import _fold_lanes, _lane_cascade
 
 Array = jnp.ndarray
 
 
 def _ff_rowsum_kernel(x_ref, oh_ref, ol_ref, s_acc, c_acc, cc_acc,
-                      *, nc: int, bc: int, lane: int):
+                      *, nc: int, lane: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -37,17 +37,8 @@ def _ff_rowsum_kernel(x_ref, oh_ref, ol_ref, s_acc, c_acc, cc_acc,
         c_acc[...] = jnp.zeros_like(c_acc)
         cc_acc[...] = jnp.zeros_like(cc_acc)
 
-    x = x_ref[...]                       # (br, bc)
-
-    def body(t, carry):
-        s, c, cc = carry                 # (br, lane) each
-        xt = lax.dynamic_slice_in_dim(x, t * lane, lane, axis=1)
-        s2, e = eft.two_sum(s, xt)
-        c2, e2 = eft.two_sum(c, e)
-        return s2, c2, cc + e2
-
-    s, c, cc = lax.fori_loop(0, bc // lane, body,
-                             (s_acc[...], c_acc[...], cc_acc[...]))
+    s, c, cc = _lane_cascade(x_ref[...], s_acc[...], c_acc[...],
+                             cc_acc[...], lane)
     s_acc[...] = s
     c_acc[...] = c
     cc_acc[...] = cc
@@ -55,20 +46,9 @@ def _ff_rowsum_kernel(x_ref, oh_ref, ol_ref, s_acc, c_acc, cc_acc,
     @pl.when(j == nc - 1)
     def _flush():
         # fold the `lane` per-lane accumulators exactly, sequentially
-        def fold(i, carry):
-            fh, fl = carry
-            sh, sl = eft.two_sum(
-                fh, lax.dynamic_slice_in_dim(s_acc[...], i, 1, axis=1)[:, 0])
-            v = sl + (fl
-                      + lax.dynamic_slice_in_dim(c_acc[...], i, 1, axis=1)[:, 0]
-                      + lax.dynamic_slice_in_dim(cc_acc[...], i, 1, axis=1)[:, 0])
-            return eft.fast_two_sum(sh, v)
-
-        br = s_acc.shape[0]
-        z = jnp.zeros((br,), jnp.float32)
-        fh, fl = lax.fori_loop(0, s_acc.shape[1], fold, (z, z))
-        oh_ref[...] = fh[:, None]
-        ol_ref[...] = fl[:, None]
+        fh, fl = _fold_lanes(s_acc[...], c_acc[...], cc_acc[...])
+        oh_ref[...] = fh
+        ol_ref[...] = fl
 
 
 @functools.partial(jax.jit, static_argnames=("br", "bc", "lane", "interpret"))
@@ -90,7 +70,7 @@ def ff_rowsum(x: Array, *, br: int = 256, bc: int = 512, lane: int = 128,
     grid = (Rp // br, nc)
     out = jax.ShapeDtypeStruct((Rp, 1), jnp.float32)
     oh, ol = pl.pallas_call(
-        functools.partial(_ff_rowsum_kernel, nc=nc, bc=bc, lane=lane),
+        functools.partial(_ff_rowsum_kernel, nc=nc, lane=lane),
         out_shape=(out, out),
         grid=grid,
         in_specs=[pl.BlockSpec((br, bc), lambda i, j: (i, j))],

@@ -8,18 +8,27 @@ calling this.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding rules in this repo
+    are ``PartitionSpec`` constraints for the compiler to propagate, which
+    ``Explicit`` axes (``jax.make_mesh``'s default in JAX 0.9) refuse."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """1-device mesh with the same axis names (smoke tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_mesh((1, n), ("data", "model"))
 
 
 def make_local_data_mesh():
@@ -32,4 +41,4 @@ def make_local_data_mesh():
     data axis — correct for TP layout experiments, inert for the mesh
     reduction tier)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))

@@ -22,6 +22,37 @@ import jax
 import jax.numpy as jnp
 
 
+def build_params(cfg, seed: int = 0):
+    """Random weights from ``seed``, built under ``jit``: the RNG
+    intermediates of an eager build would sit on the device beside the
+    finished weights and double the peak at full width."""
+    from repro.models import init_params
+    return jax.jit(lambda k: init_params(cfg, k))(jax.random.PRNGKey(seed))
+
+
+def make_requests(cfg, lens, max_new: int, rng):
+    """One engine request per prompt length, token ids drawn from ``rng``
+    (a ``numpy.random.Generator``)."""
+    import numpy as np
+    from repro.serve import Request
+    return [Request(uid=i,
+                    prompt=rng.integers(1, cfg.vocab_size,
+                                        size=int(n)).astype(np.int32),
+                    max_new=max_new)
+            for i, n in enumerate(lens)]
+
+
+def serve(eng, requests, **run_kw):
+    """Submit ``requests`` to ``eng`` and drain it.  Returns (results of
+    these requests by uid, wall seconds)."""
+    for r in requests:
+        eng.submit(r)
+    t0 = time.perf_counter()
+    results = eng.run(**run_kw)
+    dt = time.perf_counter() - t0
+    return {r.uid: results[r.uid] for r in requests}, dt
+
+
 def _start_metrics_server(observer, port: int):
     """Serve ``observer``'s registry (+ the global telemetry registry) as
     Prometheus text exposition on /metrics, in a daemon thread."""
@@ -103,6 +134,8 @@ def main():
                          "exposition on http://127.0.0.1:PORT/metrics for "
                          "the duration of the run (0 = off)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if (args.snapshot_every or args.resume) and not args.snapshot_dir:
         ap.error("--snapshot-every/--resume require --snapshot-dir")
     if (args.metrics_json or args.trace_out or args.metrics_port) \
@@ -113,13 +146,12 @@ def main():
 
     import repro.ff as ff
     from repro.configs import get_config
-    from repro.models import init_params
     from repro.train.serve_step import greedy_generate
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = build_params(cfg)
     mesh_scope = contextlib.nullcontext()
     if args.mesh:
         from repro.distributed.sharding import param_shardings
@@ -133,7 +165,7 @@ def main():
     if args.engine:
         import numpy as np
         from repro import obs
-        from repro.serve import Request, ServeEngine, resume_engine
+        from repro.serve import ServeEngine, resume_engine
         journal = (os.path.join(args.snapshot_dir, "wal.jsonl")
                    if args.snapshot_dir else None)
         observer = obs.Observer()
@@ -145,6 +177,8 @@ def main():
         rng = np.random.default_rng(1)
         lo = max(4, args.prompt_len // 2)
         lens = rng.integers(lo, args.prompt_len + 1, size=args.batch)
+        run_kw = dict(snapshot_dir=args.snapshot_dir,
+                      snapshot_every=args.snapshot_every or None)
         if args.resume:
             t0 = time.perf_counter()
             eng = resume_engine(params, cfg, args.snapshot_dir,
@@ -157,21 +191,16 @@ def main():
                   f"{len(eng.results)} completed, {n_restored} running, "
                   f"{len(eng.queue)} queued/replayed "
                   f"({time.perf_counter() - t0:.2f}s to warm state)")
+            t0 = time.perf_counter()
+            results = eng.run(**run_kw)
+            dt = time.perf_counter() - t0
         else:
             eng = ServeEngine(params, cfg, max_batch=args.batch,
                               max_ctx=args.prompt_len + args.max_new + 8,
                               kv_mode=args.kv_mode, guard=args.guard,
                               journal=journal, obs=observer)
-            for i, l in enumerate(lens):
-                eng.submit(Request(
-                    uid=i,
-                    prompt=rng.integers(1, cfg.vocab_size,
-                                        size=int(l)).astype(np.int32),
-                    max_new=args.max_new))
-        t0 = time.perf_counter()
-        results = eng.run(snapshot_dir=args.snapshot_dir,
-                          snapshot_every=args.snapshot_every or None)
-        dt = time.perf_counter() - t0
+            results, dt = serve(
+                eng, make_requests(cfg, lens, args.max_new, rng), **run_kw)
         n_tok = sum(len(r.tokens) for r in results.values())
         all_lps = np.concatenate(
             [r.logprobs for r in results.values()]

@@ -37,6 +37,8 @@ def main():
                          "loss/grad reductions through the mesh-partitioned "
                          "FF tier (compensated cross-device combines)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs import get_config
     from repro.core.policy import PrecisionPolicy

@@ -88,7 +88,7 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 import jax
@@ -550,6 +550,20 @@ class ServeEngine:
             self._prefill_cache[S] = jax.jit(step)
         return self._prefill_cache[S]
 
+    def prefill(self, prompt: np.ndarray) -> Tuple[Array, Any]:
+        """Exact-length prefill of one 1-D prompt: ``(logits (1, V),
+        cache)``.  Admission runs it; an accuracy check can re-run it to
+        recover the logits a request's first token was scored on."""
+        # the prefill cache dtype IS the page fidelity: bf16 matches the
+        # greedy_generate baseline cache bitwise; the f32 / FF page modes
+        # keep the full compute-precision K/V
+        cache_dt = jnp.bfloat16 if self.kv.kv_mode == "bf16" \
+            else jnp.float32
+        S = int(prompt.shape[0])
+        cache = init_cache(self.cfg, 1, S, dtype=cache_dt)
+        return self._prefill_fn(S)(
+            self.params, {"tokens": jnp.asarray(prompt[None])}, cache)
+
     def _deadline_passed(self, req: Request, t_sub: float,
                          step_sub: int) -> bool:
         if req.deadline_s is not None and \
@@ -596,16 +610,8 @@ class ServeEngine:
                 self.kv.seq_lens[slot] = S  # ...but only S tokens are live
             else:
                 self.kv.alloc(slot, S)      # lazy: grow() per decode step
-            # the prefill cache dtype IS the page fidelity: bf16 matches
-            # the greedy_generate baseline cache bitwise; the f32 / FF
-            # page modes keep the full compute-precision K/V
-            cache_dt = jnp.bfloat16 if self.kv.kv_mode == "bf16" \
-                else jnp.float32
-            cache = init_cache(self.cfg, 1, S, dtype=cache_dt)
             with obs_mod.annotate("serve.prefill"):
-                logits, cache = self._prefill_fn(S)(
-                    self.params, {"tokens": jnp.asarray(req.prompt[None])},
-                    cache)
+                logits, cache = self.prefill(req.prompt)
             self.kv.write_prefill(slot, {
                 "k": cache["layers"]["k"][:, 0],
                 "v": cache["layers"]["v"][:, 0]})

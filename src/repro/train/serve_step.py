@@ -7,6 +7,7 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from typing import Optional
 
@@ -67,8 +68,14 @@ def token_logprob_ff(logits: Array, token: Array):
     serving accuracy gate (logprob within 2^-40 of the f64 oracle, see
     docs/DESIGN_serving.md) therefore scores through this variant: the
     whole chain — TwoSum max-shift, FF exponentials, compensated exp-sum,
-    FF log, and the final chosen-minus-LSE subtract — stays in FF, and the
-    caller compares limb pairs."""
+    FF log1p, and the final subtract — stays in FF, and the caller
+    compares limb pairs.
+
+    The score is ``(x_tok - m) - log1p(r)`` with ``r`` the exp-sum of every
+    entry but one argmax (whose term is exactly 1).  Both parts are <= 0,
+    so nothing cancels: a near-certain row (``r`` << 1) keeps its relative
+    accuracy, where ``log(1 + r)`` of an FF-rounded ``1 + r`` would lose
+    it as 1/r."""
     import repro.core.compensated as compensated
     import repro.core.ff as core_ff
     import repro.core.ffmath as ffmath
@@ -79,13 +86,17 @@ def token_logprob_ff(logits: Array, token: Array):
     m = jnp.max(x, axis=-1, keepdims=True)
     dh, dl = T.two_sum(x, jnp.broadcast_to(-m, x.shape))
     eh, el = ffmath.exp22(dh, dl, ffmath.CORE)
-    s = core_ff.add22_accurate(
+    top = (lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+           == jnp.argmax(x, axis=-1, keepdims=True))
+    eh = jnp.where(top, jnp.float32(0), eh)
+    el = jnp.where(top, jnp.float32(0), el)
+    r = core_ff.add22_accurate(
         compensated.ff_sum_blocked(eh, axis=-1, block=256),
         compensated.ff_sum_blocked(el, axis=-1, block=256))
-    logs = FF(*ffmath.log22(s.hi, s.lo, ffmath.CORE))
-    lse = core_ff.add212(logs, jnp.squeeze(m, axis=-1))
+    l1p = FF(*ffmath.log1p22(r.hi, r.lo, ffmath.CORE))
     chosen = jnp.take_along_axis(x, token[:, None], axis=-1)[:, 0]
-    return core_ff.add212(FF(-lse.hi, -lse.lo), chosen)
+    gap = FF(*T.two_sum(chosen, -jnp.squeeze(m, axis=-1)))   # exact
+    return core_ff.add22_accurate(gap, FF(-l1p.hi, -l1p.lo))
 
 
 def greedy_generate(params, cfg: ModelConfig, prompt: Array, max_new: int,

@@ -112,7 +112,6 @@ def check_x64_literal_canonicalization(mode: str = "jit") -> HazardReport:
     config; the raw probe re-builds the literal-in-scope shape and asks
     whether it still canonicalizes to f32."""
     import jax
-    import jax.experimental
     import jax.numpy as jnp
     from jax import lax
 
@@ -145,7 +144,7 @@ def check_x64_literal_canonicalization(mode: str = "jit") -> HazardReport:
     # silent wrongness, but proof the canonicalization still happens.
     @jax.jit
     def raw(h, l):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = (lax.convert_element_type(h, jnp.float64)
                  + lax.convert_element_type(l, jnp.float64))
             r = 0.5 * x * (1.0 + lax.erf(x / jnp.sqrt(jnp.asarray(2.0))))

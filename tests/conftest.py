@@ -15,8 +15,10 @@ import numpy as np
 import pytest
 
 
-@pytest.fixture(scope="session")
+@pytest.fixture(scope="module")
 def rng():
+    # per module, not per session: a file's data must not depend on which
+    # other files a parallel worker happened to run before it
     return np.random.default_rng(1234)
 
 

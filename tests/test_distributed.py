@@ -11,6 +11,8 @@ import sys
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
+
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -31,7 +33,7 @@ def test_param_spec_rules():
     from repro.models import init_params
 
     # 1-device mesh with both axis names still produces valid specs
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("olmoe_1b_7b")
     params = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.random.PRNGKey(0))
@@ -48,7 +50,7 @@ def test_validate_spec_divisibility():
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import validate_spec
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         shape = {"data": 16, "model": 16}
@@ -76,7 +78,8 @@ import dataclasses
 
 cfg = get_config('granite_3_2b').reduced(num_layers=2, vocab_size=512)
 spec = dataclasses.replace(SHAPES['train_4k'], seq_len=256, global_batch=8)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 lowered, compiled = dr._lower_cell(cfg, spec, mesh, PrecisionPolicy.make('ff_master'))
 from repro.launch import hlo_costs, hlo_analysis as hla
 parsed = hlo_costs.analyze_text(compiled.as_text())
@@ -102,7 +105,8 @@ from repro.launch import hlo_costs
 
 cfg = get_config('mamba2_370m').reduced(num_layers=2, vocab_size=512)
 spec = dataclasses.replace(SHAPES['decode_32k'], seq_len=1024, global_batch=8)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 lowered, compiled = dr._lower_cell(cfg, spec, mesh, PrecisionPolicy.make('ff_master'))
 parsed = hlo_costs.analyze_text(compiled.as_text())
 print(json.dumps({'flops': parsed['flops']}))
@@ -162,14 +166,15 @@ from repro.checkpoint import checkpoint as ckpt
 
 devs = jax.devices()
 n = len(devs)
-mesh_a = jax.make_mesh((n // 4, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh_a = make_mesh((n // 4, 4), ("data", "model"))
 tree = {"w": jnp.arange(64 * 8, dtype=jnp.float32).reshape(64, 8)}
 sharded = jax.device_put(tree, NamedSharding(mesh_a, P("data", "model")))
 d = tempfile.mkdtemp()
 ckpt.save(d, 1, sharded)
 
 # restart onto a different mesh shape (elastic scale-up of model axis)
-mesh_b = jax.make_mesh((n // 8, 8), ("data", "model"))
+mesh_b = make_mesh((n // 8, 8), ("data", "model"))
 restored, step, _ = ckpt.load(d, tree)
 resharded = jax.device_put(restored, NamedSharding(mesh_b, P("data", "model")))
 ok = bool(jnp.all(resharded["w"] == tree["w"]))

@@ -12,6 +12,7 @@ import os
 
 import repro.ff as ff
 from repro.ff import docgen, dispatch
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 API = os.path.join(ROOT, "docs", "API.md")
@@ -36,7 +37,7 @@ def test_api_matrix_is_static_markdown():
     import jax
 
     t1 = ff.render_api_table()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with ff.on_mesh(mesh, axis="data"), ff.use(matmul="dot2"):
         t2 = ff.render_api_table()
     assert t1 == t2

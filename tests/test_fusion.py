@@ -18,6 +18,8 @@ jitted XLA already differ by ~1 ulp through f32 div/sqrt chains for any
 program (the backend rewrites e.g. x/sqrt(y) under jit), which has nothing
 to do with fusion.
 """
+import warnings
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -431,6 +433,38 @@ def test_long_row_falls_back_to_jnp(rng, monkeypatch):
         got = ff.softmax(x, impl="pallas")
     want = jax.jit(_softmax_ref)(x)
     _assert_ulp(got, want, 2, "fallback softmax")
+
+
+@pytest.mark.parametrize("op", ["logsumexp", "softmax", "mean_sq",
+                                "norm_stats"])
+def test_long_row_resolves_to_generic_default(op, monkeypatch):
+    """A whole-row TPU default that cannot hold the row (a 49155-token
+    vocabulary) is not resolved at all: the generic default is, named
+    ``shape_default`` in telemetry, and nothing falls back in the call."""
+    from repro import obs
+    from repro.kernels import ff_fused
+    monkeypatch.setattr(dispatch, "backend", lambda: "tpu")
+    wide = (8, ff_fused.MAX_FUSED_COLS + 1)
+    before = obs.REGISTRY.snapshot()
+    assert dispatch.resolve_name(op, shape=wide) == \
+        dispatch._DEFAULTS[op]["*"]
+    assert dispatch.resolve_name(op, shape=(8, 2048)) == \
+        dispatch._DEFAULTS[op]["tpu"]
+    grew = [k for k, v in obs.REGISTRY.delta(before)["counters"].items()
+            if v and f'op="{op}"' in k and 'source="shape_default"' in k]
+    assert len(grew) == 1, grew
+
+
+def test_fallback_warning_category(rng, monkeypatch):
+    """A kernel impl that runs its jnp formulation warns with its own
+    category, so a caller (the chip smoke) can make it an error."""
+    from repro.kernels import ff_fused
+    monkeypatch.setattr(ff_fused, "MAX_FUSED_COLS", 128)
+    x = jnp.asarray(rng.standard_normal((4, 300)).astype(np.float32))
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", ff.FFFallbackWarning)
+        with pytest.raises(ff.FFFallbackWarning, match="falling back"):
+            ff.logsumexp(x, impl="pallas")
 
 
 # ---------------------------------------------------------------------------

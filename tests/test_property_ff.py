@@ -86,7 +86,8 @@ _safe_hi = st.floats(
 
 # near-overflow hi limbs: the top decades of the f32 range
 _big_hi = st.floats(
-    min_value=1e30, max_value=3.0e38, width=32,
+    min_value=float(np.float32(1e30)), max_value=float(np.float32(3.0e38)),
+    width=32,   # bounds must be f32-representable at width=32
 ).flatmap(lambda m: st.sampled_from([m, -m]))
 
 
@@ -153,7 +154,8 @@ def test_prop_mul22_adversarial_limbs(a, b):
     if not (1e-30 < abs(exact) < 1e30):
         return                                   # paper §6.1 exclusions
     got = ff64(mul22(a, b))
-    assert abs(got - exact) <= 2.0 ** -43 * abs(exact)
+    # the 2^-125 floor absorbs flush-to-zero dropping a subnormal lo limb
+    assert abs(got - exact) <= max(2.0 ** -43 * abs(exact), 2.0 ** -125)
 
 
 @settings(max_examples=150, deadline=None)
@@ -166,7 +168,7 @@ def test_prop_div22_adversarial_limbs(a, b):
     if not (1e-30 < abs(exact) < 1e30):
         return
     got = ff64(div22(a, b))
-    assert abs(got - exact) <= 2.0 ** -42 * abs(exact)
+    assert abs(got - exact) <= max(2.0 ** -42 * abs(exact), 2.0 ** -125)
 
 
 @settings(max_examples=150, deadline=None)

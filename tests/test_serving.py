@@ -443,17 +443,46 @@ def test_engine_eos_forces_per_step_sync(served):
 # FF token-logprob accuracy tier
 # --------------------------------------------------------------------------
 
-def test_token_logprob_ff_oracle(rng):
-    """Limb-pair score within 2^-40 of the exact f64 log-softmax over a
-    wide-dynamic-range vocab row."""
-    logits = jnp.asarray(
-        (rng.standard_normal((4, 4096)) * 8.0).astype(np.float32))
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    s = token_logprob_ff(logits, tok)
-    lg64 = np.asarray(logits, np.float64)
-    m = lg64.max(-1, keepdims=True)
-    lse = np.log(np.exp(lg64 - m).sum(-1)) + m[:, 0]
-    ref = lg64[np.arange(4), np.asarray(tok)] - lse
+def _log_softmax_f64(logits: np.ndarray, tok: np.ndarray) -> np.ndarray:
+    """f64 oracle as (x_tok - m) - log1p(sum of the non-argmax terms):
+    log(sum) would round 1 + r and lose the relative accuracy of a
+    near-certain row's small logprob."""
+    x = np.asarray(logits, np.float64)
+    rows = np.arange(x.shape[0])
+    m = x.max(-1)
+    e = np.exp(x - m[:, None])
+    e[rows, x.argmax(-1)] = 0.0
+    return (x[rows, tok] - m) - np.log1p(e.sum(-1))
+
+
+def _check_logprob_ff(logits: np.ndarray, tok: np.ndarray) -> None:
+    s = jax.jit(token_logprob_ff)(jnp.asarray(logits), jnp.asarray(tok))
+    ref = _log_softmax_f64(logits, tok)
     got = np.asarray(s.hi, np.float64) + np.asarray(s.lo, np.float64)
-    err = float(np.max(np.abs(got - ref) / np.maximum(np.abs(ref), 1e-30)))
-    assert err <= TOL, f"token_logprob_ff err {err:.3e} > 2^-40"
+    err = np.abs(got - ref) / np.maximum(np.abs(ref), 1e-30)
+    assert err.max() <= TOL, (
+        f"token_logprob_ff err 2^{np.log2(err.max()):.1f} > 2^-40 on "
+        f"{int((err > TOL).sum())} of {len(err)} rows")
+
+
+@pytest.mark.parametrize("rows", [4, 300])
+def test_token_logprob_ff_oracle(rng, rows):
+    """Limb-pair score within 2^-40 (relative) of the f64 log-softmax over
+    wide-dynamic-range (sigma=8) vocab rows, for the argmax token."""
+    logits = (rng.standard_normal((rows, 4096)) * 8.0).astype(np.float32)
+    _check_logprob_ff(logits, logits.argmax(-1).astype(np.int32))
+
+
+@pytest.mark.parametrize("margin", [8.0, 20.0, 40.0])
+def test_token_logprob_ff_near_certain(margin):
+    """A row whose top logit leads by ``margin``: the argmax logprob is
+    about -V e^-margin, far below 1 in magnitude, and must keep 2^-40
+    relative; a non-argmax token and a tied top are scored too."""
+    r = np.random.default_rng(int(margin))
+    logits = r.standard_normal((6, 4096)).astype(np.float32)
+    top = r.integers(0, 4096, 6)
+    logits[np.arange(6), top] = logits.max(-1) + np.float32(margin)
+    logits[5, (top[5] + 1) % 4096] = logits[5, top[5]]         # tie
+    tok = logits.argmax(-1).astype(np.int32)
+    tok[4] = (top[4] + 7) % 4096                                # not argmax
+    _check_logprob_ff(logits, tok)

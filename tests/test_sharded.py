@@ -21,6 +21,8 @@ import warnings
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
+
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -43,7 +45,7 @@ def test_on_mesh_resolution():
     import jax
     import repro.ff as ff
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert ff.resolve_name("matmul") != "sharded"
     assert ff.mesh_default("matmul") == "sharded"
     assert ff.mesh_default("sum") == "sharded"
@@ -72,7 +74,7 @@ def test_on_mesh_bad_axis():
     import jax
     import repro.ff as ff
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="not in mesh axes"):
         ff.on_mesh(mesh, axis="nonexistent")
 
@@ -129,7 +131,8 @@ import jax, jax.numpy as jnp
 import repro.ff as ff
 
 out = {}
-mesh = jax.make_mesh((8,), ("x",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("x",))
 rng = np.random.default_rng(0)
 M, K, N = 128, 2048, 128
 A = jnp.asarray(rng.standard_normal((M, K)).astype(np.float32))
@@ -200,7 +203,7 @@ with ff.on_mesh(mesh6, axis="x"):
 out["acc6_oracle"] = float((np.abs(np.asarray(R6.to_f64()) - E6) / S6).max())
 
 # 2-axis mesh: tuple-axis partitioning folds one axis at a time
-mesh24 = jax.make_mesh((2, 4), ("a", "b"))
+mesh24 = make_mesh((2, 4), ("a", "b"))
 with ff.on_mesh(mesh24, axis=("a", "b")):
     R24 = jax.jit(lambda a, b: ff.matmul(a, b, impl="sharded_accurate"))(A, B)
 out["acc24_oracle"] = err(R24)
@@ -241,7 +244,8 @@ import jax, jax.numpy as jnp
 import repro.ff as ff
 
 out = {}
-mesh = jax.make_mesh((8,), ("x",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("x",))
 rng = np.random.default_rng(1)
 M, K, N = 64, 1024, 64
 A = jnp.asarray(rng.standard_normal((M, K)).astype(np.float32))
@@ -287,7 +291,7 @@ opt = AdamW(learning_rate=1e-3, ff=True)
 data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size, seq_len=64,
                               global_batch=8))
 batch = {k: jnp.asarray(v) for k, v in data.batch(0).items()}
-mesh2 = jax.make_mesh((8, 1), ("data", "model"))
+mesh2 = make_mesh((8, 1), ("data", "model"))
 with ff.policy("ff_reduce"):
     step1 = jax.jit(make_train_step(cfg, None, opt))
     stepm = jax.jit(make_train_step(cfg, None, opt, mesh=mesh2))

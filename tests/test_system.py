@@ -55,3 +55,24 @@ def test_system_train_then_serve(tmp_path):
         lambda p, t, c: decode_step(p, t, jnp.int32(S), c, cfg, policy))(
         params, tok, cache)
     assert bool(jnp.all(jnp.isfinite(logits2)))
+
+
+def test_compile_cache_location(tmp_path, monkeypatch):
+    """The launchers' cache goes where JAX_COMPILATION_CACHE_DIR says, else
+    into the checkout's .jax_cache, and stays off outside a checkout."""
+    from repro.launch import compile_cache
+
+    set_dirs = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: set_dirs.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert compile_cache.enable_compile_cache() == str(tmp_path / "env")
+    assert set_dirs == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.setattr(compile_cache, "CHECKOUT", str(tmp_path))
+    assert compile_cache.enable_compile_cache() is None
+    assert set_dirs == []
+    (tmp_path / "pyproject.toml").write_text("")
+    path = str(tmp_path / ".jax_cache")
+    assert compile_cache.enable_compile_cache() == path
+    assert set_dirs == [("jax_compilation_cache_dir", path)]
