@@ -39,6 +39,14 @@ def dense_init(key, shape, in_axis=0, dtype=jnp.float32):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
+def cast_weight(w: Array, dtype) -> Array:
+    """A stored weight in the compute dtype, under ``jax.named_scope("cast")``
+    so that a compiled program can report the conversions' device time as
+    one part (``repro.obs.parts``).  The scope is HLO metadata only."""
+    with jax.named_scope("cast"):
+        return w.astype(dtype)
+
+
 # ---------------------------------------------------------------------------
 # norms
 # ---------------------------------------------------------------------------
@@ -61,7 +69,7 @@ def rms_norm(x: Array, w: Array, eps: float, ff_stats: bool = False) -> Array:
     else:
         ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
     scale = lax.rsqrt(ms + eps).astype(x.dtype)      # (B,S,1), cheap in bf16
-    return x * scale * w.astype(x.dtype)
+    return x * scale * cast_weight(w, x.dtype)
 
 
 def layer_norm(x: Array, w: Array, b: Array, eps: float,
@@ -191,14 +199,14 @@ def attn_apply(p: Params, x: Array, cfg: ModelConfig, *,
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     dt = x.dtype
-    q = (x @ p["wq"].astype(dt)).reshape(B, S, cfg.num_heads, hd)
-    k = (x @ p["wk"].astype(dt)).reshape(B, S, cfg.num_kv_heads, hd)
-    v = (x @ p["wv"].astype(dt)).reshape(B, S, cfg.num_kv_heads, hd)
+    q = (x @ cast_weight(p["wq"], dt)).reshape(B, S, cfg.num_heads, hd)
+    k = (x @ cast_weight(p["wk"], dt)).reshape(B, S, cfg.num_kv_heads, hd)
+    v = (x @ cast_weight(p["wv"], dt)).reshape(B, S, cfg.num_kv_heads, hd)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     o = flash_attention(q, k, v, causal=causal, block_q=cfg.attn_block_q,
                         block_kv=cfg.attn_block_kv, impl=attn_impl)
-    return o.reshape(B, S, cfg.num_heads * hd) @ p["wo"].astype(dt)
+    return o.reshape(B, S, cfg.num_heads * hd) @ cast_weight(p["wo"], dt)
 
 
 def attn_prefill(p: Params, x: Array, cfg: ModelConfig, *, positions: Array,
@@ -207,9 +215,9 @@ def attn_prefill(p: Params, x: Array, cfg: ModelConfig, *, positions: Array,
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     dt = x.dtype
-    q = (x @ p["wq"].astype(dt)).reshape(B, S, cfg.num_heads, hd)
-    k = (x @ p["wk"].astype(dt)).reshape(B, S, cfg.num_kv_heads, hd)
-    v = (x @ p["wv"].astype(dt)).reshape(B, S, cfg.num_kv_heads, hd)
+    q = (x @ cast_weight(p["wq"], dt)).reshape(B, S, cfg.num_heads, hd)
+    k = (x @ cast_weight(p["wk"], dt)).reshape(B, S, cfg.num_kv_heads, hd)
+    v = (x @ cast_weight(p["wv"], dt)).reshape(B, S, cfg.num_kv_heads, hd)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     o = flash_attention(q, k, v, causal=True, block_q=cfg.attn_block_q,
@@ -219,7 +227,8 @@ def attn_prefill(p: Params, x: Array, cfg: ModelConfig, *, positions: Array,
         cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
     cache["v"] = lax.dynamic_update_slice_in_dim(
         cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
-    return o.reshape(B, S, cfg.num_heads * hd) @ p["wo"].astype(dt), cache
+    out = o.reshape(B, S, cfg.num_heads * hd) @ cast_weight(p["wo"], dt)
+    return out, cache
 
 
 def attn_decode(p: Params, x: Array, cfg: ModelConfig, *,
@@ -230,9 +239,9 @@ def attn_decode(p: Params, x: Array, cfg: ModelConfig, *,
     assert S == 1
     hd = cfg.resolved_head_dim
     dt = x.dtype
-    q = (x @ p["wq"].astype(dt)).reshape(B, 1, cfg.num_heads, hd)
-    k = (x @ p["wk"].astype(dt)).reshape(B, 1, cfg.num_kv_heads, hd)
-    v = (x @ p["wv"].astype(dt)).reshape(B, 1, cfg.num_kv_heads, hd)
+    q = (x @ cast_weight(p["wq"], dt)).reshape(B, 1, cfg.num_heads, hd)
+    k = (x @ cast_weight(p["wk"], dt)).reshape(B, 1, cfg.num_kv_heads, hd)
+    v = (x @ cast_weight(p["wv"], dt)).reshape(B, 1, cfg.num_kv_heads, hd)
     posv = jnp.full((B, 1), pos, jnp.int32)
     q = apply_rope(q, posv, cfg.rope_theta)
     k = apply_rope(k, posv, cfg.rope_theta)
@@ -242,7 +251,8 @@ def attn_decode(p: Params, x: Array, cfg: ModelConfig, *,
     cache["v"] = lax.dynamic_update_slice(
         cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
     o = decode_attention(q, cache["k"], cache["v"], pos + 1, impl=attn_impl)
-    return o.reshape(B, 1, cfg.num_heads * hd) @ p["wo"].astype(dt), cache
+    out = o.reshape(B, 1, cfg.num_heads * hd) @ cast_weight(p["wo"], dt)
+    return out, cache
 
 
 def attn_cache_init(cfg: ModelConfig, batch: int, max_len: int,
@@ -274,13 +284,13 @@ def mlp_apply(p: Params, x: Array, ff_math: bool = False) -> Array:
     instead of the ~2^-24 f32 builtin; the default is bitwise-identical
     to the pre-``ff.math`` library."""
     dt = x.dtype
-    pre = x @ p["w_gate"].astype(dt)
+    pre = x @ cast_weight(p["w_gate"], dt)
     if ff_math:
         g = ff.to_f32(ff.silu(pre.astype(jnp.float32))).astype(dt)
     else:
         g = jax.nn.silu(pre)
-    u = x @ p["w_up"].astype(dt)
-    return (g * u) @ p["w_down"].astype(dt)
+    u = x @ cast_weight(p["w_up"], dt)
+    return (g * u) @ cast_weight(p["w_down"], dt)
 
 
 # ---------------------------------------------------------------------------
@@ -296,7 +306,7 @@ def embed_params(key, cfg: ModelConfig) -> Params:
 
 
 def embed_apply(p: Params, tokens: Array, dtype) -> Array:
-    return p["tok"].astype(dtype)[tokens]
+    return cast_weight(p["tok"], dtype)[tokens]
 
 
 def unembed_apply(p: Params, x: Array, cfg: ModelConfig,
@@ -306,7 +316,8 @@ def unembed_apply(p: Params, x: Array, cfg: ModelConfig,
     the loss/logprob reductions, so the builtin's ~2^-24 error otherwise
     floors everything the FF loss machinery measures downstream."""
     dt = x.dtype
-    w = p["unembed"].astype(dt) if "unembed" in p else p["tok"].astype(dt).T
+    w = cast_weight(p["unembed"], dt) if "unembed" in p \
+        else cast_weight(p["tok"], dt).T
     logits = x @ w
     if cfg.logit_softcap:
         c = cfg.logit_softcap

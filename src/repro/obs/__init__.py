@@ -18,7 +18,10 @@ lazily, and a cycle here would break the registry bootstrap):
 
 * **Profiling** (:mod:`repro.obs.profiling`): ``obs.enable()`` scope
   gating ``jax.profiler.TraceAnnotation``/``named_scope`` wrappers around
-  prefill, decode, Ozaki matmul, and the sharded combines.
+  Ozaki matmul and the sharded combines, and ``obs.span``: the serving
+  engine's step phases, in the trace recorder and the profiler at once.
+  :mod:`repro.obs.parts` maps a compiled program's instructions to the
+  named parts of its scopes.
 
 ``python -m repro.obs`` runs an instrumented serving smoke and emits both
 artifacts — see :mod:`repro.obs.__main__`.
@@ -31,12 +34,14 @@ from typing import Optional
 from repro.obs.registry import (Counter, Gauge, Histogram, MetricsRegistry,
                                 LOG2_BUCKETS)
 from repro.obs.trace import TraceRecorder, ENGINE_TID
-from repro.obs.profiling import annotate, enable, enabled
+from repro.obs.profiling import annotate, enable, enabled, span
+from repro.obs.parts import DECODE_PARTS, part_of, program_parts
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "LOG2_BUCKETS",
     "TraceRecorder", "ENGINE_TID",
-    "annotate", "enable", "enabled",
+    "annotate", "enable", "enabled", "span",
+    "DECODE_PARTS", "part_of", "program_parts",
     "REGISTRY", "Observer",
     "record_resolution", "record_tune_lookup", "record_warning",
     "record_guard_violation", "record_journal_event",

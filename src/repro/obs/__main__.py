@@ -13,7 +13,9 @@ the Chrome request trace, and the ``obs.enable()`` profiler annotations
     round-trip) with ONE complete ``request`` span per submitted
     request, each carrying a documented terminal status, and monotone
     non-negative timestamps;
-  * guard/serve counters and latency histograms populated.
+  * guard/serve counters and latency histograms populated;
+  * the engine's ``serve.*`` step spans and the decode program's
+    ``program`` record of named parts are in the trace.
 
 Exits non-zero listing every violated check.  ``--metrics-json`` /
 ``--trace-out`` write the artifacts (CI uploads them).
@@ -113,9 +115,12 @@ def main() -> int:
           + snap["counters"].get('serve_requests_total{status="DEGRADED"}',
                                  0) >= 1,
           "engine request counters populated")
-    check(snap["histograms"].get("serve_decode_step_seconds",
+    check(snap["histograms"].get("serve_flush_seconds",
                                  {}).get("count", 0) > 0,
-          "decode-step latency histogram populated")
+          "flush (host blocked on the device) histogram populated")
+    check(snap["counters"].get(
+        'serve_programs_built_total{program="jit_step_decode"}', 0) == 1,
+          "decode program built once")
     prom = observer.registry.to_prometheus() + obs.REGISTRY.to_prometheus()
     check("serve_guard_events_total" in prom
           and "ff_dispatch_resolutions_total" in prom,
@@ -135,6 +140,17 @@ def main() -> int:
           "trace timestamps monotone non-negative after export sort")
     check(all(e.get("dur", 0) >= 0 for e in evs if e["ph"] == "X"),
           "span durations non-negative")
+    engine = {e["name"] for e in evs
+              if e["ph"] == "X" and e["tid"] == obs.ENGINE_TID}
+    check({"serve.step", "serve.prefill", "serve.decode_step",
+           "serve.flush"} <= engine,
+          "engine step spans recorded under obs.enable()")
+    progs = [e["args"] for e in evs
+             if e["ph"] == "M" and e["name"] == "program"]
+    check(len(progs) == 1 and progs[0]["name"] == "jit_step_decode"
+          and set(progs[0]["parts"].values())
+          >= {"attn", "kv", "mlp", "head", "sample"},
+          "decode program publishes its named parts (f32 compute: no cast)")
 
     if args.metrics_json:
         observer.dump_metrics(args.metrics_json)

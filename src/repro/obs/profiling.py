@@ -1,7 +1,8 @@
-"""Scoped profiler annotations (``obs.enable()`` / ``obs.annotate``).
+"""Scoped profiler annotations (``obs.enable()`` / ``obs.annotate`` /
+``obs.span``).
 
-The hot paths — engine prefill, the jitted decode step, the Ozaki matmul
-slices, the sharded combines — are wrapped in :func:`annotate`.  Outside
+The library's hot paths — the Ozaki matmul slices, the sharded
+combines — are wrapped in :func:`annotate`.  Outside
 an :class:`enable` scope that wrapper is a no-op ``nullcontext`` (one
 thread-local list check, nothing allocated), so the default serving path
 pays effectively nothing.  Inside the scope it enters both
@@ -13,6 +14,13 @@ pays effectively nothing.  Inside the scope it enters both
 
 mirroring the ``ff.policy`` thread-local-stack idiom: enter the scope
 before tracing/profiling, per-thread, re-entrant.
+
+:func:`span` is the engine's host-side form: one ``with`` block records a
+``ph="X"`` event on the recorder's engine track AND enters a
+``TraceAnnotation`` of the same name, so the serving engine's step
+phases stand in both sinks under one name (no ``named_scope``: a span
+names host work between dispatches, not traced ops).  Outside an
+:class:`enable` scope it is the same shared no-op context.
 """
 
 from __future__ import annotations
@@ -20,7 +28,7 @@ from __future__ import annotations
 import contextlib
 import threading
 
-__all__ = ["enable", "enabled", "annotate"]
+__all__ = ["enable", "enabled", "annotate", "span"]
 
 
 class _ObsState(threading.local):
@@ -70,3 +78,40 @@ def annotate(name: str):
     stack.enter_context(jax.profiler.TraceAnnotation(name))
     stack.enter_context(jax.named_scope(name))
     return stack
+
+
+class _Span:
+    """The :func:`span` context: the annotation is entered first and left
+    last, so the recorder's interval lies inside the profiler's."""
+
+    __slots__ = ("_trace", "_name", "_args", "_ann", "_t0")
+
+    def __init__(self, trace, name: str, args: dict):
+        self._trace, self._name, self._args = trace, name, args
+
+    def __enter__(self):
+        import jax.profiler
+        self._ann = jax.profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
+        self._t0 = self._trace.now()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self._trace.now()
+        self._trace.complete(self._name, self._t0, t1 - self._t0,
+                             args=self._args or None)
+        self._ann.__exit__(*exc)
+        return False
+
+
+_NULL = contextlib.nullcontext()
+
+
+def span(observer, name: str, **args):
+    """An engine span of ``observer`` (an :class:`repro.obs.Observer`):
+    a ``ph="X"`` event on its trace's engine track plus a
+    ``jax.profiler.TraceAnnotation``, both named ``name``, with ``args``
+    on the event.  Records nothing outside ``obs.enable()``."""
+    if not enabled():
+        return _NULL
+    return _Span(observer.trace, name, args)

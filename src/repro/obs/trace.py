@@ -5,11 +5,15 @@ Emits the subset of the Trace Event Format that Perfetto (and Chrome's
 
 * ``ph="X"`` complete spans — one ``request`` span per request plus its
   ``queued`` / ``prefill`` / ``decode`` children, laid out one Perfetto
-  track per request (``tid`` = request uid);
+  track per request (``tid`` = request uid), and under ``obs.enable()``
+  the engine's step phases (``serve.*``, :func:`repro.obs.span`) on the
+  engine track;
 * ``ph="C"`` counter tracks — queue depth, active batch rows, page-pool
   occupancy, sampled once per scheduler step;
 * ``ph="i"`` instants — preemptions, quarantines, snapshot writes,
-  ``sync_every`` host syncs, journal compactions.
+  programs built, journal compactions;
+* ``ph="M"`` metadata — track names, and the ``program`` record of a
+  compiled program's named parts.
 
 Timestamps are microseconds from ``time.perf_counter_ns`` relative to
 recorder construction, so a trace is self-consistent and monotonic
@@ -51,6 +55,11 @@ class TraceRecorder:
     def _meta(self, tid: int, name: str) -> None:
         self._append({"ph": "M", "pid": self._pid, "tid": tid, "ts": 0,
                       "name": "thread_name", "args": {"name": name}})
+
+    def metadata(self, name: str, args: Dict[str, Any]) -> None:
+        """A ``ph="M"`` record on the engine track (e.g. ``program``)."""
+        self._append({"ph": "M", "pid": self._pid, "tid": ENGINE_TID,
+                      "ts": 0, "name": name, "args": args})
 
     def _append(self, ev: Dict[str, Any]) -> None:
         with self._lock:
@@ -108,11 +117,14 @@ class TraceRecorder:
     # -- structural summary (for tests) -----------------------------------
     def span_structure(self) -> List[tuple]:
         """Timestamp-free span summary: sorted ``(tid, name, status)``
-        tuples for every complete span.  Two runs of the same request set
-        must agree here regardless of ``sync_every`` batching."""
+        tuples for every complete span on a request track.  Two runs of
+        the same request set must agree here regardless of ``sync_every``
+        batching.  The engine track is left out: its ``serve.flush``
+        spans follow the host syncs, whose number ``sync_every`` sets by
+        design."""
         out = []
         for ev in self.events():
-            if ev["ph"] != "X":
+            if ev["ph"] != "X" or ev["tid"] < REQUEST_TID_BASE:
                 continue
             status = (ev.get("args") or {}).get("status", "")
             out.append((ev["tid"], ev["name"], status))

@@ -101,8 +101,9 @@ from repro.ff.guard import FFGuardWarning, health_mask, report_violation
 from repro.ff.scope import resolve_policy
 from repro.models import init_cache
 from repro.models.config import ModelConfig
-from repro.models.layers import (apply_rope, decode_attention, mlp_apply,
-                                 rms_norm, embed_apply, unembed_apply)
+from repro.models.layers import (apply_rope, cast_weight, decode_attention,
+                                 mlp_apply, rms_norm, embed_apply,
+                                 unembed_apply)
 from repro.train.serve_step import (greedy_generate, make_prefill_step,
                                     token_logprob, token_logprob_ff)
 from repro.serve.journal import RequestJournal
@@ -337,7 +338,6 @@ class ServeEngine:
         self.obs = obs if obs is not None else obs_mod.Observer()
         self.guard_stats = _GuardStats(self.obs.registry)
         self._req_trace: Dict[int, Dict[str, Any]] = {}
-        self._last_flush_ts = self.obs.trace.now()
         self.journal: Optional[RequestJournal] = None
         self._snap_cover: Optional[set] = None  # uids of last async save
         # NOTE: the page planes are deliberately NOT donated — on the CPU
@@ -352,6 +352,7 @@ class ServeEngine:
             return r.hi, r.lo
         self._score_ff = jax.jit(_ff_limbs)
         self._prefill_cache: Dict[int, Any] = {}
+        self._decode_built = False
         self.decode_steps = 0
         if journal is not None:
             self.attach_journal(journal)
@@ -364,7 +365,7 @@ class ServeEngine:
         ff_pages = kv.kv_mode == "ff_bf16"
         probe = self.guard_mode != "off"
 
-        def step(params, token, lens, bt, active, planes):
+        def step_decode(params, token, lens, bt, active, planes):
             """token: (B,1) int32; lens: (B,) tokens already cached;
             bt: (B, npg) page table (-1 empty); active: (B,) bool;
             planes: dict of (L, NP, ps, KV, hd).  Returns (next greedy
@@ -373,62 +374,79 @@ class ServeEngine:
             argmax and BOTH scoring tiers run inside the one jitted step,
             so per decode step the host sees four (B,) vectors (plus the
             flag), not the (B, V) logits.  Math per active row is exactly
-            the ``model.decode_step`` dense body at that row's position."""
+            the ``model.decode_step`` dense body at that row's position.
+
+            Named scopes split the program into the parts of
+            ``repro.obs.DECODE_PARTS``: ``cast`` (weights to the compute
+            dtype, ``cast_weight``), ``attn``, ``kv`` (page write, gather,
+            pool update), ``mlp``, ``head`` and ``sample``; the token
+            lookup is ``embed``, which is no part."""
             dt = jnp.dtype(cfg.compute_dtype)
             B = token.shape[0]
             H, KVh = cfg.num_heads, cfg.num_kv_heads
             hd = cfg.resolved_head_dim
             NP = next(iter(planes.values())).shape[1]
-            x = embed_apply(params["embed"], token, dt)
-            # the page/offset every row writes its new K/V to (drop page
-            # NP for inactive rows -> scatter is a no-op there)
-            rowpage = bt[jnp.arange(B), lens // ps]
-            wpage = jnp.where(active, rowpage, jnp.int32(NP))
-            woff = lens % ps
-            gidx = jnp.maximum(bt, 0)          # gather table (garbage rows
+            with jax.named_scope("embed"):
+                x = embed_apply(params["embed"], token, dt)
+            with jax.named_scope("kv"):
+                # the page/offset every row writes its new K/V to (drop
+                # page NP for inactive rows -> scatter is a no-op there)
+                rowpage = bt[jnp.arange(B), lens // ps]
+                wpage = jnp.where(active, rowpage, jnp.int32(NP))
+                woff = lens % ps
+                gidx = jnp.maximum(bt, 0)      # gather table (garbage rows
             posv = lens[:, None]               # are masked by lens later)
 
             def body(carry, scanned):
                 h, bad = carry
                 lp = scanned[0]
                 pl = dict(zip(sorted(planes), scanned[1:]))
-                z = rms_norm(h, lp["ln1"], cfg.norm_eps,
-                             ff_stats=policy.ff_reductions)
                 ap = lp["attn"]
-                q = (z @ ap["wq"].astype(dt)).reshape(B, 1, H, hd)
-                k = (z @ ap["wk"].astype(dt)).reshape(B, 1, KVh, hd)
-                v = (z @ ap["wv"].astype(dt)).reshape(B, 1, KVh, hd)
-                q = apply_rope(q, posv, cfg.rope_theta)
-                k = apply_rope(k, posv, cfg.rope_theta)
-                if probe:
-                    # non-finite new K/V in this layer poisons the row's
-                    # cache for every later step: flag at the source
-                    bad = bad | ~jnp.isfinite(
-                        k.astype(jnp.float32)).all(axis=(1, 2, 3))
-                    bad = bad | ~jnp.isfinite(
-                        v.astype(jnp.float32)).all(axis=(1, 2, 3))
+                with jax.named_scope("attn"):
+                    z = rms_norm(h, lp["ln1"], cfg.norm_eps,
+                                 ff_stats=policy.ff_reductions)
+                    q = (z @ cast_weight(ap["wq"], dt)).reshape(B, 1, H, hd)
+                    k = (z @ cast_weight(ap["wk"], dt)).reshape(
+                        B, 1, KVh, hd)
+                    v = (z @ cast_weight(ap["wv"], dt)).reshape(
+                        B, 1, KVh, hd)
+                    q = apply_rope(q, posv, cfg.rope_theta)
+                    k = apply_rope(k, posv, cfg.rope_theta)
+                    if probe:
+                        # non-finite new K/V in this layer poisons the
+                        # row's cache for every later step: flag at the
+                        # source
+                        bad = bad | ~jnp.isfinite(
+                            k.astype(jnp.float32)).all(axis=(1, 2, 3))
+                        bad = bad | ~jnp.isfinite(
+                            v.astype(jnp.float32)).all(axis=(1, 2, 3))
                 gathered = {}
-                for base, new in (("k", k), ("v", v)):
-                    if ff_pages:
-                        hi, lo = ff_split(new[:, 0])
-                        pl[f"{base}_hi"] = pl[f"{base}_hi"].at[
-                            wpage, woff].set(hi, mode="drop")
-                        pl[f"{base}_lo"] = pl[f"{base}_lo"].at[
-                            wpage, woff].set(lo, mode="drop")
-                        merged = ff_merge(pl[f"{base}_hi"][gidx],
-                                          pl[f"{base}_lo"][gidx])
-                    else:
-                        pdt = pl[base].dtype
-                        pl[base] = pl[base].at[wpage, woff].set(
-                            new[:, 0].astype(pdt), mode="drop")
-                        merged = pl[base][gidx]
-                    gathered[base] = merged.reshape(B, npg * ps, KVh, hd)
-                o = decode_attention(q, gathered["k"], gathered["v"],
-                                     lens + 1, impl=policy.attention)
-                h = h + (o.reshape(B, 1, H * hd) @ ap["wo"].astype(dt))
-                z = rms_norm(h, lp["ln2"], cfg.norm_eps,
-                             ff_stats=policy.ff_reductions)
-                f = mlp_apply(lp["ffn"], z, ff_math=policy.ff_math)
+                with jax.named_scope("kv"):
+                    for base, new in (("k", k), ("v", v)):
+                        if ff_pages:
+                            hi, lo = ff_split(new[:, 0])
+                            pl[f"{base}_hi"] = pl[f"{base}_hi"].at[
+                                wpage, woff].set(hi, mode="drop")
+                            pl[f"{base}_lo"] = pl[f"{base}_lo"].at[
+                                wpage, woff].set(lo, mode="drop")
+                            merged = ff_merge(pl[f"{base}_hi"][gidx],
+                                              pl[f"{base}_lo"][gidx])
+                        else:
+                            pdt = pl[base].dtype
+                            pl[base] = pl[base].at[wpage, woff].set(
+                                new[:, 0].astype(pdt), mode="drop")
+                            merged = pl[base][gidx]
+                        gathered[base] = merged.reshape(
+                            B, npg * ps, KVh, hd)
+                with jax.named_scope("attn"):
+                    o = decode_attention(q, gathered["k"], gathered["v"],
+                                         lens + 1, impl=policy.attention)
+                    h = h + (o.reshape(B, 1, H * hd)
+                             @ cast_weight(ap["wo"], dt))
+                with jax.named_scope("mlp"):
+                    z = rms_norm(h, lp["ln2"], cfg.norm_eps,
+                                 ff_stats=policy.ff_reductions)
+                    f = mlp_apply(lp["ffn"], z, ff_math=policy.ff_math)
                 return (h + f, bad), tuple(pl[n] for n in sorted(pl))
 
             bad0 = jnp.zeros((B,), jnp.bool_)
@@ -436,21 +454,24 @@ class ServeEngine:
                 body, (x, bad0),
                 (params["layers"],) + tuple(
                     planes[n] for n in sorted(planes)))
-            x = rms_norm(x, params["final_norm"], cfg.norm_eps,
-                         ff_stats=policy.ff_reductions)
-            logits = unembed_apply(params["embed"], x, cfg,
-                                   ff_math=policy.ff_math)[:, 0]
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            lp = token_logprob(logits, nxt, policy)
-            lp_ff = token_logprob_ff(logits, nxt)
-            if probe:
-                # score health: non-finite f32 score, or an FF score pair
-                # that is non-finite / unnormalized (|lo| > ulp(hi)/2)
-                bad = bad | ~jnp.isfinite(lp) | ~health_mask(lp_ff)
+            with jax.named_scope("head"):
+                x = rms_norm(x, params["final_norm"], cfg.norm_eps,
+                             ff_stats=policy.ff_reductions)
+                logits = unembed_apply(params["embed"], x, cfg,
+                                       ff_math=policy.ff_math)[:, 0]
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                lp = token_logprob(logits, nxt, policy)
+                lp_ff = token_logprob_ff(logits, nxt)
+                if probe:
+                    # score health: non-finite f32 score, or an FF score
+                    # pair that is non-finite / unnormalized
+                    # (|lo| > ulp(hi)/2)
+                    bad = bad | ~jnp.isfinite(lp) | ~health_mask(lp_ff)
             return (nxt, lp, lp_ff.hi, lp_ff.lo, bad,
                     dict(zip(sorted(planes), updated)))
 
-        return step
+        return step_decode
 
     # -- request lifecycle -------------------------------------------------
 
@@ -548,7 +569,24 @@ class ServeEngine:
         if S not in self._prefill_cache:
             step = make_prefill_step(self.cfg, self.policy)
             self._prefill_cache[S] = jax.jit(step)
+            self._program_built("jit_step_prefill", prompt_len=S)
         return self._prefill_cache[S]
+
+    def _program_built(self, name: str, prompt_len: Optional[int] = None,
+                       compiled_text=None) -> None:
+        """Count a program the engine builds (``serve_programs_built_total``
+        and a ``program_built`` instant: a build inside a measured window
+        is a compile there).  Under ``obs.enable()``, ``compiled_text``
+        (a thunk giving the optimized HLO) publishes the program's named
+        parts as a ``program`` metadata record."""
+        self.obs.registry.counter("serve_programs_built_total",
+                                  program=name).inc()
+        self.obs.trace.instant("program_built", args={
+            "name": name, "prompt_len": prompt_len})
+        if compiled_text is not None and obs_mod.enabled():
+            module, parts = obs_mod.program_parts(compiled_text())
+            self.obs.trace.metadata("program", {"name": module,
+                                                "parts": parts})
 
     def prefill(self, prompt: np.ndarray) -> Tuple[Array, Any]:
         """Exact-length prefill of one 1-D prompt: ``(logits (1, V),
@@ -587,6 +625,12 @@ class ServeEngine:
     def _admit(self) -> None:
         """Join waiting requests into free rows while pages allow (FIFO —
         no request starves behind an unschedulable head-of-line)."""
+        if not self.queue:
+            return
+        with obs_mod.span(self.obs, "serve.admit"):
+            self._admit_queue()
+
+    def _admit_queue(self) -> None:
         admitted = False
         while self.queue:
             q = self.queue[0]
@@ -610,11 +654,11 @@ class ServeEngine:
                 self.kv.seq_lens[slot] = S  # ...but only S tokens are live
             else:
                 self.kv.alloc(slot, S)      # lazy: grow() per decode step
-            with obs_mod.annotate("serve.prefill"):
-                logits, cache = self.prefill(req.prompt)
-            self.kv.write_prefill(slot, {
-                "k": cache["layers"]["k"][:, 0],
-                "v": cache["layers"]["v"][:, 0]})
+            with obs_mod.span(self.obs, "serve.prefill", uid=int(req.uid),
+                              prompt_len=S):
+                tok, lp, lp_ff = self._prefill_first(slot, req.prompt)
+            # the request's prefill ends with its first token and both
+            # scores on the host
             ts_pf = self.obs.trace.now()
             self.obs.trace.complete("prefill", ts_adm, ts_pf - ts_adm,
                                     tid=tid, args={"prompt_len": S})
@@ -622,12 +666,9 @@ class ServeEngine:
                 "serve_prefill_seconds").observe((ts_pf - ts_adm) / 1e6)
             if tr is not None:
                 tr["admit"] = ts_pf
-            tok = int(jnp.argmax(logits, -1)[0])
-            lp = float(self._score(logits, jnp.asarray([tok], jnp.int32))[0])
-            lph, lpl = self._score_ff(logits, jnp.asarray([tok], jnp.int32))
             state = {"req": req, "prompt_len": S,
                      "tokens": [tok], "logprobs": [lp],
-                     "logprobs_ff": [(float(lph[0]), float(lpl[0]))],
+                     "logprobs_ff": [lp_ff],
                      "pending": 0, "start_step": self.decode_steps,
                      "t_sub": q["t_sub"], "step_sub": q["step_sub"],
                      "admit_seq": self._admit_seq}
@@ -637,12 +678,29 @@ class ServeEngine:
             self._token_dev = self._token_dev.at[slot].set(tok)
             admitted = True
             if self.guard_mode != "off" and not (
-                    np.isfinite(lp) and np.isfinite(float(lph[0]))):
+                    np.isfinite(lp) and np.isfinite(lp_ff[0])):
                 self._quarantine(slot, "non-finite prefill score")
             elif self._finished(state):
                 self._retire(slot)
         if admitted and self.guard_mode != "off":
             self._audit_paging()
+
+    def _prefill_first(self, slot: int, prompt: np.ndarray
+                       ) -> Tuple[int, float, Tuple[float, float]]:
+        """Prefill ``prompt`` into ``slot``'s pages and return its first
+        greedy token with the token's f32 and FF (hi, lo) scores: every
+        program is dispatched before the host reads the three in one
+        ``device_get``."""
+        logits, cache = self.prefill(prompt)
+        self.kv.write_prefill(slot, {
+            "k": cache["layers"]["k"][:, 0],
+            "v": cache["layers"]["v"][:, 0]})
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        lp = self._score(logits, tok)
+        lph, lpl = self._score_ff(logits, tok)
+        with obs_mod.span(self.obs, "serve.prefill.wait"):
+            tok, lp, lph, lpl = jax.device_get((tok, lp, lph, lpl))
+        return int(tok[0]), float(lp[0]), (float(lph[0]), float(lpl[0]))
 
     def _finished(self, state: Dict[str, Any]) -> bool:
         if len(state["tokens"]) >= state["req"].max_new:
@@ -810,23 +868,26 @@ class ServeEngine:
         return any(s is not None for s in self._slots)
 
     def _step_decode(self) -> None:
-        if not self._ensure_growth():
-            return
-        active_np = np.asarray([s is not None for s in self._slots])
-        lens = np.asarray(
-            [self._row_len(s) if s else 0 for s in self._slots],
-            np.int32)
-        t0 = self.obs.trace.now()
-        with obs_mod.annotate("serve.decode_step"):
-            nxt, lp, lph, lpl, bad, self.kv.planes = self._decode(
-                self.params, self._token_dev[:, None],
-                jnp.asarray(lens), jnp.asarray(self.kv.block_table),
-                jnp.asarray(active_np), self.kv.planes)
-        # host-side dispatch latency: jax dispatch is async, so this is
-        # the step's *enqueue* cost; the blocking device time lands in
-        # serve_flush_seconds at the sync_every boundary
-        self.obs.registry.histogram("serve_decode_step_seconds").observe(
-            (self.obs.trace.now() - t0) / 1e6)
+        with obs_mod.span(self.obs, "serve.schedule"):
+            if not self._ensure_growth():
+                return
+        with obs_mod.span(self.obs, "serve.decode_prep"):
+            active_np = np.asarray([s is not None for s in self._slots])
+            lens = np.asarray(
+                [self._row_len(s) if s else 0 for s in self._slots],
+                np.int32)
+            args = (self.params, self._token_dev[:, None],
+                    jnp.asarray(lens), jnp.asarray(self.kv.block_table),
+                    jnp.asarray(active_np), self.kv.planes)
+        with obs_mod.span(self.obs, "serve.decode_step"):
+            nxt, lp, lph, lpl, bad, self.kv.planes = self._decode(*args)
+        if not self._decode_built:
+            self._decode_built = True
+            # the program just run, from jit's cache: no second compile
+            self._program_built(
+                "jit_step_decode",
+                compiled_text=lambda: self._decode.lower(
+                    *args).compile().as_text())
         self._token_dev = nxt
         self._pending.append({"step": self.decode_steps, "nxt": nxt,
                               "lp": lp, "lph": lph, "lpl": lpl,
@@ -848,15 +909,17 @@ class ServeEngine:
             return
         entries = self._pending
         self._pending = []
+        with obs_mod.span(self.obs, "serve.flush", steps=len(entries)):
+            self._apply_synced(entries)
+
+    def _apply_synced(self, entries: List[Dict[str, Any]]) -> None:
         t0 = self.obs.trace.now()
-        host = jax.device_get([(e["nxt"], e["lp"], e["lph"], e["lpl"],
-                                e["bad"]) for e in entries])
-        t1 = self.obs.trace.now()
-        self.obs.trace.instant("host_sync",
-                               args={"steps": len(entries)})
+        with obs_mod.span(self.obs, "serve.flush.wait"):
+            host = jax.device_get([(e["nxt"], e["lp"], e["lph"], e["lpl"],
+                                    e["bad"]) for e in entries])
+        # host time blocked on the device (and the copies back)
         self.obs.registry.histogram("serve_flush_seconds").observe(
-            (t1 - t0) / 1e6)
-        n_synced = 0
+            (self.obs.trace.now() - t0) / 1e6)
         flagged: Dict[int, bool] = {}
         for (e, (nxt, lp, lph, lpl, bad)) in zip(entries, host):
             nxt = np.asarray(nxt, np.int32)
@@ -871,16 +934,9 @@ class ServeEngine:
                 state["logprobs_ff"].append(
                     (float(lph[slot]), float(lpl[slot])))
                 state["pending"] -= 1
-                n_synced += 1
                 self._last_tok[slot] = tok
                 if bool(bad[slot]):
                     flagged[slot] = True
-        # decode throughput over the inter-flush window (tokens made
-        # host-visible per wall second between consecutive syncs)
-        if n_synced and t1 > self._last_flush_ts:
-            self.obs.registry.histogram("serve_tokens_per_s").observe(
-                n_synced / ((t1 - self._last_flush_ts) / 1e6))
-        self._last_flush_ts = t1
         if flagged:
             self.guard_stats["flagged_rows"] += len(flagged)
         for slot in list(flagged):
@@ -900,6 +956,7 @@ class ServeEngine:
             self._audit_paging()
 
     def _must_flush(self) -> bool:
+
         if not self._pending:
             return False
         if len(self._pending) >= self.sync_every:
@@ -925,8 +982,18 @@ class ServeEngine:
         decoding (staggered arrivals join the running batch at the next
         step — see ``examples/serve_lm.py``).  Never raises for
         off-nominal scheduling conditions — every request ends in a
-        terminal status from :data:`STATUSES`."""
-        self._expire_queue()
+        terminal status from :data:`STATUSES`.
+
+        Under ``obs.enable()`` the step is a ``serve.step`` span whose
+        children name its phases (``serve.schedule``, ``serve.admit`` >
+        ``serve.prefill`` > ``serve.prefill.wait``, ``serve.decode_prep``,
+        ``serve.decode_step``, ``serve.flush`` > ``serve.flush.wait``)."""
+        with obs_mod.span(self.obs, "serve.step"):
+            return self._step()
+
+    def _step(self) -> bool:
+        with obs_mod.span(self.obs, "serve.schedule"):
+            self._expire_queue()
         self._admit()
         if any(s is not None for s in self._slots):
             self._step_decode()

@@ -21,12 +21,13 @@ Array = jnp.ndarray
 
 def make_prefill_step(cfg: ModelConfig,
                       policy: Optional[PrecisionPolicy] = None):
-    """policy=None reads the ambient ``repro.ff.policy`` scope at build."""
+    """policy=None reads the ambient ``repro.ff.policy`` scope at build.
+    Jitted, the program is named ``jit_step_prefill``."""
     policy = resolve_policy(policy)
 
-    def step(params, batch: Dict[str, Array], cache):
+    def step_prefill(params, batch: Dict[str, Array], cache):
         return prefill(params, batch, cfg, cache, policy)
-    return step
+    return step_prefill
 
 
 def make_decode_step(cfg: ModelConfig,

@@ -205,18 +205,23 @@ def test_trace_structure_invariant_under_sync_every(served):
     """sync_every=4 batches device_gets but must not change the request
     lifecycle: both engines produce the SAME span structure (one queued +
     prefill + decode + request span per uid, same terminal statuses) and
-    the same tokens.  The trace exports as Chrome JSON that survives a
-    json round-trip with monotone timestamps."""
+    the same tokens.  The engine track's ``serve.flush`` spans follow the
+    host syncs, so their number differs by design, and the structure
+    summarises request tracks only.  The trace exports as Chrome JSON
+    that survives a json round-trip with monotone timestamps."""
     reqs = _mixed_requests(np.random.default_rng(41), 3, max_new=7)
-    structures, results = {}, {}
+    structures, results, flushes = {}, {}, {}
     for n in (1, 4):
         eng = ServeEngine(served, CFG, max_batch=2, page_size=8,
                           max_ctx=48, sync_every=n, obs=obs.Observer())
-        for r in reqs:
-            eng.submit(Request(uid=r.uid, prompt=r.prompt,
-                               max_new=r.max_new))
-        results[n] = eng.run()
+        with obs.enable():
+            for r in reqs:
+                eng.submit(Request(uid=r.uid, prompt=r.prompt,
+                                   max_new=r.max_new))
+            results[n] = eng.run()
         structures[n] = eng.obs.trace.span_structure()
+        flushes[n] = sum(1 for e in eng.obs.trace.events()
+                         if e["ph"] == "X" and e["name"] == "serve.flush")
 
         payload = json.loads(json.dumps(eng.obs.to_chrome_trace()))
         assert payload["traceEvents"], "trace must not be empty"
@@ -229,6 +234,8 @@ def test_trace_structure_invariant_under_sync_every(served):
             names = sorted(e["name"] for e in spans if e["tid"] == tid)
             assert names == ["decode", "prefill", "queued", "request"]
 
+    assert flushes[1] > flushes[4] > 0
+    assert all(tid != obs.ENGINE_TID for tid, _, _ in structures[1])
     assert structures[1] == structures[4], (
         "span structure must be a lifecycle invariant, not a function of "
         "host-sync batching")
@@ -240,7 +247,10 @@ def test_trace_structure_invariant_under_sync_every(served):
 
 def test_engine_metrics_populated(served):
     """A plain run populates the per-engine counters and latency
-    histograms, and token accounting agrees with the results."""
+    histograms, and token accounting agrees with the results: one
+    prefill observation per admission, one flush observation per host
+    sync, one build per program (the decode step and each prompt
+    length's prefill)."""
     reqs = _mixed_requests(np.random.default_rng(42), 3, max_new=6)
     eng = ServeEngine(served, CFG, max_batch=2, page_size=8, max_ctx=48)
     for r in reqs:
@@ -250,9 +260,16 @@ def test_engine_metrics_populated(served):
     assert snap["counters"]['serve_requests_total{status="OK"}'] == 3
     emitted = sum(len(r.tokens) for r in res.values())
     assert snap["counters"]["serve_tokens_emitted_total"] == emitted
-    for h in ("serve_prefill_seconds", "serve_decode_step_seconds",
-              "serve_flush_seconds"):
-        assert snap["histograms"][h]["count"] > 0, h
+    assert snap["histograms"]["serve_prefill_seconds"]["count"] == 3
+    assert snap["histograms"]["serve_flush_seconds"]["count"] > 0
+    assert "serve_decode_step_seconds" not in snap["histograms"]
+    assert "serve_tokens_per_s" not in snap["histograms"]
+    built = {k: v for k, v in snap["counters"].items()
+             if k.startswith("serve_programs_built_total")}
+    lens = {len(r.prompt) for r in reqs}
+    assert built == {
+        'serve_programs_built_total{program="jit_step_decode"}': 1,
+        'serve_programs_built_total{program="jit_step_prefill"}': len(lens)}
 
 
 def test_trace_recorder_primitives():
@@ -263,6 +280,8 @@ def test_trace_recorder_primitives():
                  args={"status": "OK"})
     rec.instant("quarantine", tid=0, args={"uid": 5})
     rec.counter("queue", {"depth": 2})
+    rec.complete("serve.step", t0, 5.0)            # engine track
+    rec.metadata("program", {"name": "jit_f", "parts": {"dot.1": "head"}})
     out = rec.to_chrome_trace()
     assert out["displayTimeUnit"] == "ms"
     phs = [e["ph"] for e in out["traceEvents"]]
