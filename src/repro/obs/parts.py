@@ -29,6 +29,10 @@ One exception: XLA moves the converts of the scanned weight stacks out of
 the loop and writes the moved convert with no metadata, so a convert
 outside fused computations with no metadata is ``cast``.
 
+The computations an instruction applies (a reduction's ``to_apply``, a
+sort's ``comparator``) run inside that instruction and hold no operation
+of their own, as fused computations do not.
+
 Stdlib only, like the rest of ``repro.obs``.
 """
 
@@ -49,6 +53,7 @@ _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s+=\s(.*)$")
 _OPCODE = re.compile(r"\s([a-z][\w-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=%?([^\s,}]+)")
+_APPLIES = re.compile(r"\b(?:to_apply|comparator)=%?([^\s,}]+)")
 _REF = re.compile(r"%([^\s,(){}]+)")
 _SCAFFOLD = ("while", "body", "cond")
 
@@ -139,11 +144,11 @@ def program_parts(hlo_text: str, parts: Sequence[str] = DECODE_PARTS
                   ) -> Tuple[Optional[str], Dict[str, str]]:
     """``(module name, {instruction name: part})`` of an optimized HLO
     module.  The map holds the instructions that can run as operations
-    of their own (those outside fused computations) and have a part;
-    an instruction it does not hold is ``other``."""
+    of their own (those outside fused and applied computations) and have
+    a part; an instruction it does not hold is ``other``."""
     module, comps = _parse(hlo_text, parts)
     fused = {i.calls for instrs in comps.values() for i in instrs.values()
-             if i.calls}
+             if i.calls} | set(_APPLIES.findall(hlo_text))
     memo: Dict[str, Optional[str]] = {}
 
     def inner(comp: str) -> Optional[str]:

@@ -181,6 +181,13 @@ def _check_cfg(cfg: ModelConfig) -> None:
             "moe_num_experts=0 (dense FFN)")
 
 
+def _bytes_by_dtype(leaves) -> Dict[str, int]:
+    out: Dict[str, int] = {}
+    for w in leaves:
+        out[w.dtype.name] = out.get(w.dtype.name, 0) + int(w.nbytes)
+    return out
+
+
 def _empty_result(req: Request, status: str, detail: str) -> GenResult:
     return GenResult(uid=req.uid, tokens=np.zeros((0,), np.int32),
                      logprobs=np.zeros((0,), np.float32),
@@ -285,6 +292,20 @@ class ServeEngine:
     requests in original order (see :meth:`attach_journal` /
     :func:`resume_engine`); :meth:`snapshot` / :meth:`restore` freeze and
     rebuild the full engine with token-for-token replay parity.
+
+    Weights: the engine serves them in ``cfg.compute_dtype``.  When
+    ``cfg.param_dtype`` differs, every floating leaf of ``params`` is
+    converted once, before the first program runs (the first
+    :meth:`step` or :meth:`prefill`), and ``self.params`` is that tree
+    from then on; no program converts a weight again.  Every weight the
+    programs read was rounded to the compute dtype anyway, so every
+    matrix product gets the values it got before (on the CPU the served
+    tokens and scores are bitwise those of programs that convert in
+    place; on a TPU the programs compile differently and agree to the
+    compute dtype's rounding, not bit for bit).  The engine never
+    mutates or deletes the caller's arrays; a caller that drops its own
+    reference after construction lets each stored leaf go as soon as its
+    copy is made, so the conversion needs the stored tree plus one leaf.
     """
 
     def __init__(self, params: Any, cfg: ModelConfig, *,
@@ -304,6 +325,7 @@ class ServeEngine:
         if sync_every < 1:
             raise ValueError("sync_every must be >= 1")
         self.params = params
+        self._resident = False          # set once _weights() has run
         self.cfg = cfg
         self.policy = resolve_policy(policy)
         self.max_batch = max_batch
@@ -345,8 +367,10 @@ class ServeEngine:
         # per step (measured 2x step latency); the non-donated step keeps
         # the pool update as cheap aliased buffers
         self._decode = jax.jit(self._make_decode_step())
-        self._score = jax.jit(
-            lambda lg, tk: token_logprob(lg, tk, self.policy))
+        # no closure holds ``self``: a dropped engine frees its weights at
+        # once, not at the cyclic collector's next pass
+        policy = self.policy
+        self._score = jax.jit(lambda lg, tk: token_logprob(lg, tk, policy))
         def _ff_limbs(lg, tk):
             r = token_logprob_ff(lg, tk)
             return r.hi, r.lo
@@ -378,9 +402,10 @@ class ServeEngine:
 
             Named scopes split the program into the parts of
             ``repro.obs.DECODE_PARTS``: ``cast`` (weights to the compute
-            dtype, ``cast_weight``), ``attn``, ``kv`` (page write, gather,
-            pool update), ``mlp``, ``head`` and ``sample``; the token
-            lookup is ``embed``, which is no part."""
+            dtype, ``cast_weight``: empty, as the engine serves weights
+            already in it), ``attn``, ``kv`` (page write, gather, pool
+            update), ``mlp``, ``head`` and ``sample``; the token lookup
+            is ``embed``, which is no part."""
             dt = jnp.dtype(cfg.compute_dtype)
             B = token.shape[0]
             H, KVh = cfg.num_heads, cfg.num_kv_heads
@@ -564,6 +589,41 @@ class ServeEngine:
             return "QUEUED"
         raise KeyError(f"unknown request uid {uid}")
 
+    def _weights(self) -> Any:
+        """The params every program reads: on the first call, the caller's
+        tree with every floating leaf converted to the compute dtype when
+        ``cfg.param_dtype`` differs from it.  Leaf by leaf: the engine's
+        reference to a stored leaf goes once its copy is ready, so a
+        caller that kept no reference of its own frees each one as the
+        conversion proceeds.  Records a ``weights_resident`` instant and
+        the ``serve_weight_bytes{dtype}`` gauges."""
+        if self._resident:
+            return self.params
+        self._resident = True
+        t0 = time.perf_counter()
+        dt = jnp.dtype(self.cfg.compute_dtype)
+        leaves, tree = jax.tree_util.tree_flatten(self.params)
+        self.params = None
+        before = sum(int(w.nbytes) for w in leaves)
+        converted = 0
+        if jnp.dtype(self.cfg.param_dtype) != dt:
+            for i in range(len(leaves)):
+                if jnp.issubdtype(leaves[i].dtype, jnp.floating) \
+                        and leaves[i].dtype != dt:
+                    leaves[i] = jax.block_until_ready(
+                        jnp.asarray(leaves[i], dt))
+                    converted += 1
+        self.params = jax.tree_util.tree_unflatten(tree, leaves)
+        after = _bytes_by_dtype(leaves)
+        for name, n in after.items():
+            self.obs.registry.gauge("serve_weight_bytes", dtype=name).set(n)
+        self.obs.trace.instant("weights_resident", args={
+            "leaves": converted, "dtype": dt.name,
+            "bytes_before": before,
+            "bytes_after": sum(after.values()),
+            "seconds": time.perf_counter() - t0})
+        return self.params
+
     def _prefill_fn(self, S: int):
         """Exact-length prefill, jit-cached per distinct prompt length."""
         if S not in self._prefill_cache:
@@ -600,7 +660,7 @@ class ServeEngine:
         S = int(prompt.shape[0])
         cache = init_cache(self.cfg, 1, S, dtype=cache_dt)
         return self._prefill_fn(S)(
-            self.params, {"tokens": jnp.asarray(prompt[None])}, cache)
+            self._weights(), {"tokens": jnp.asarray(prompt[None])}, cache)
 
     def _deadline_passed(self, req: Request, t_sub: float,
                          step_sub: int) -> bool:
@@ -749,7 +809,7 @@ class ServeEngine:
         detail = f"guard: {why}; retried on the fast tier"
         try:
             toks, lps = greedy_generate(
-                self.params, self.cfg, jnp.asarray(req.prompt[None]),
+                self._weights(), self.cfg, jnp.asarray(req.prompt[None]),
                 req.max_new, cache_len=state["prompt_len"] + req.max_new,
                 policy=self._fast_policy(), return_logprobs=True,
                 eos_id=self.eos_id)
@@ -876,7 +936,7 @@ class ServeEngine:
             lens = np.asarray(
                 [self._row_len(s) if s else 0 for s in self._slots],
                 np.int32)
-            args = (self.params, self._token_dev[:, None],
+            args = (self._weights(), self._token_dev[:, None],
                     jnp.asarray(lens), jnp.asarray(self.kv.block_table),
                     jnp.asarray(active_np), self.kv.planes)
         with obs_mod.span(self.obs, "serve.decode_step"):

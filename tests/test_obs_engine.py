@@ -155,7 +155,9 @@ def test_program_names_and_parts(params):
              if e["ph"] == "M" and e["name"] == "program"]
     assert len(progs) == 1 and progs[0]["name"] == "jit_step_decode"
     found = set(progs[0]["parts"].values())
-    assert {"cast", "kv", "head", "sample"} <= found
+    assert {"kv", "head", "sample"} <= found
+    # the engine serves weights already in the compute dtype
+    assert "cast" not in found
     built = [e["args"] for e in events if e["name"] == "program_built"]
     assert built[0]["name"] == "jit_step_prefill"
     assert {b["name"] for b in built} == {"jit_step_prefill",
@@ -251,6 +253,34 @@ def test_program_parts_on_hlo_text():
     assert parts["dot.9"] == "head" and parts["argmax.1"] == "sample"
     assert "gather.1" not in parts               # embed: no part -> other
     assert "while.1" not in parts and "convert.1" not in parts  # fused
+
+
+HLO_REDUCER = """HloModule jit_step_decode, is_scheduled=true
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0), metadata={op_name="reduce"}
+  %b = f32[] parameter(1), metadata={op_name="reduce"}
+  %gt.0 = pred[] compare(%a, %b), direction=GT, metadata={op_name="gt"}
+  %select.0 = f32[] select(%gt.0, %a, %b), metadata={op_name="select_n"}
+  %convert.2 = bf16[] convert(%select.0)
+  ROOT %convert.3 = f32[] convert(%convert.2)
+}
+
+ENTRY %main (lg: f32[8,16]) -> f32[8] {
+  %lg = f32[8,16]{1,0} parameter(0)
+  %c = f32[] constant(0)
+  ROOT %reduce.1 = f32[8]{0} reduce(%lg, %c), dimensions={1}, to_apply=%region_0.1, metadata={op_name="jit(step_decode)/sample/argmax"}
+}
+"""
+
+
+def test_program_parts_skip_applied_computations():
+    """A reduction's ``to_apply`` runs inside the reduction: its
+    instructions are no operations, so a convert there is not ``cast``."""
+    _, parts = program_parts(HLO_REDUCER)
+    assert parts["reduce.1"] == "sample"
+    assert not {"a", "b", "gt.0", "select.0", "convert.2",
+                "convert.3"} & set(parts)
 
 
 # -- the shared clock --------------------------------------------------------
