@@ -14,7 +14,8 @@ configurations state; at each position of the same prompts and served
 tokens it reads the gap of the token it ranks first.  The benchmark's own
 runs never run the control.  Prints one JSON line per seed and a summary:
 the largest program reading (the lower end of each limit) and the
-smallest control reading (the upper end).
+smallest control reading (the upper end), of every number the harness
+reads, also one the cell's limits leave out.
 """
 
 from __future__ import annotations
@@ -45,10 +46,10 @@ def main(argv=None) -> int:
         except run.DeviceError as e:
             run.log(f"no result: {e}")
             return 2
-        prog = {k: c["value"] for k, c in res["checks"].items()}
+        prog = res["control"]["program"]
         for k in lower:
             lower[k] = max(lower[k], prog[k])
-        ctl = {k: c["value"] for k, c in res["control"]["checks"].items()}
+        ctl = res["control"]["readings"]
         for k in upper:
             upper[k] = min(upper[k], ctl[k])
         print(json.dumps({"seed": seed, "correct": res["correct"],

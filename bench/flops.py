@@ -8,10 +8,11 @@ change to the program can push a share computed from them past 100%:
   attention over the row's live context, 4 * layers * heads * head_dim
   per cached position (q.k and p.v).
 * Bytes per decode step: every weight of those products once, at 2 bytes
-  a parameter (bfloat16, the compute type), plus the live keys and values
-  of every active row at the cache's bytes, plus the new ones written.
-  The program stores float32 weights today, so it must move at least
-  twice the weight bytes counted here.
+  a parameter (bfloat16, the compute type, in which the engine holds the
+  served weights), plus the live keys and values of every active row at
+  the cache's bytes, plus the new ones written.
+
+``bench/reference/dense_gqa.py`` hands these counts to the readers.
 """
 
 from __future__ import annotations

@@ -1,7 +1,9 @@
 """decode_roofline: the least time the traced decode steps could take on
 the chip, each the larger of its FLOPs over the bf16 peak and its bytes
 (weights once at 2 bytes a parameter, live keys and values at the
-cache's bytes; ``bench/flops.py``) over the HBM bandwidth, as a share of
+cache's bytes; ``decode_step_flops`` and ``decode_step_bytes`` of the
+configuration's module, ``bench/reference/<arch>.py``) over the HBM
+bandwidth, as a share of
 their device time, in %."""
 
 import trace_reduce
@@ -14,7 +16,7 @@ def read(run, name):
     pk = run["peaks"]
     if not mods or not calls or not pk:
         return None
-    fl = run["flops"]
+    fl = run["arch"]
     least = sum(max(fl.decode_step_flops(run["sizes"], lens)
                     / pk["flops_bf16_per_s"],
                     fl.decode_step_bytes(run["sizes"], lens, run["kv_bytes"])

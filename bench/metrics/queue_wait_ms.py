@@ -1,11 +1,11 @@
-"""queue_wait_ms.pNN: NN-th percentile of the engine's ``queued`` spans
-(submit to admission) that end in the window, in ms."""
+"""queue_wait_ms.pNN[.<cell>]: NN-th percentile of the engine's ``queued``
+spans (submit to admission) that end in the window, in ms."""
 
 import stats
 
 
 def read(run, name):
-    q = float(name.split(".p", 1)[1]) if ".p" in name else 50.0
+    q = stats.name_percentile(name)
     lo, hi = run["obs_window"]
     waits = [e["dur"] / 1e3 for e in run["engine_events"]
              if e.get("ph") == "X" and e["name"] == "queued"

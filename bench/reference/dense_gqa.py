@@ -1,6 +1,12 @@
-"""Plain reference of the dense GQA decoder that both configurations run.
+"""The dense GQA decoder's account in the benchmark, and its plain reference.
 
-Straight ``jax.numpy`` in float32, every matrix product at
+The harness resolves a configuration's ``reference`` to this module and
+takes from it the shape numbers (``sizes``), the weights from the seed
+(``make_weights``, ``bench/weights.py``), the least work of a decode step
+for the readers (``decode_step_flops``, ``decode_step_bytes``,
+``bench/flops.py``) and the reference itself (``score``).
+
+The reference is straight ``jax.numpy`` in float32, every matrix product at
 ``Precision.HIGHEST``: token embedding, then per layer RMSNorm ->
 attention (RoPE on q and k, rotate-half convention; grouped-query heads,
 head ``h`` reading kv head ``h // (H / KV)``; causal softmax scaled by
@@ -26,6 +32,9 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from flops import decode_step_bytes, decode_step_flops  # noqa: F401
+from weights import make_weights, sizes  # noqa: F401
 
 HI = lax.Precision.HIGHEST
 E4M3_MAX = 448.0

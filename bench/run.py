@@ -10,6 +10,16 @@ The cell ``<config>.<mix>`` is resolved by name: the sizes from
 ``bench/metrics/<metric>.py`` (or ``<stem>.py``, the name up to its first
 dot).  ``BENCHMARK.json`` says which metrics the cell reports.
 
+The configuration's ``reference`` names its architecture's module,
+``bench/reference/<reference>.py``, the whole account of that
+architecture in the benchmark: ``sizes(conf)`` (the shape numbers the
+counts read), ``make_weights(conf, seed)`` (the weights, on the device,
+in the layout the program reads), ``decode_step_flops(sizes, lens)`` and
+``decode_step_bytes(sizes, lens, kv_bytes)`` (the least work of a decode
+step, for the readers) and ``score(...)`` (the plain reference).  This
+file names no configuration and no architecture, so a configuration of
+another architecture comes with its own files alone.
+
 The harness is a client of the public serving engine
 (``repro.serve.ServeEngine``: ``submit``, ``step``, ``status``,
 ``results``).  Set-up makes the weights from the seed on the device,
@@ -22,7 +32,8 @@ plain reference (``bench/reference``) scores a sample of the finished
 requests, drawn from the seed with the longest among them.
 
 Standard error carries the diagnostics (device, dispatch resolutions,
-compilations in the window, generator lateness, peak memory, medians)
+compilations in the window, generator lateness, the loop iteration that
+ran longest and the engine spans in it, peak memory, medians)
 and, as its last lines, each compared number beside its limit.  The last
 line of standard output is one JSON object.  Without a TPU, or on a
 device missing from ``bench/peaks.json``, it exits 2 and prints no
@@ -104,7 +115,10 @@ def cell_metrics(bench: Dict, cell: str, trace: bool) -> List[Dict]:
 
 
 def load_reader(name: str):
-    for stem in (name, name.split(".")[0]):
+    """The reader of metric ``name``: ``bench/metrics/<name>.py``, else
+    ``<stem>.py`` (the name up to its first dot), else the name without
+    its ``_pNN`` part (``ttft_p90_ms`` is read by ``ttft_ms.py``)."""
+    for stem in (name, name.split(".")[0], re.sub(r"_p\d+(?=_)", "", name)):
         path = os.path.join(BENCH, "metrics", f"{stem}.py")
         if os.path.exists(path):
             spec = importlib.util.spec_from_file_location(
@@ -201,22 +215,54 @@ def compile_counter() -> CompileCounter:
 
 # -- the program ---------------------------------------------------------------
 
+# published keys of a configuration file -> the ModelConfig field each sets
+PUBLISHED = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
+             "num_attention_heads": "num_heads",
+             "num_key_value_heads": "num_kv_heads",
+             "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+             "head_dim": "head_dim", "rope_theta": "rope_theta",
+             "rms_norm_eps": "norm_eps", "norm_eps": "norm_eps"}
+# ModelConfig fields that say how the program runs, not what model it runs
+KNOBS = {"attn_block_q", "attn_block_kv", "loss_chunk", "max_seq_len"}
+
+
 def model_config(conf: Dict):
     """The program's ``ModelConfig`` at the sizes of the configuration
     file, refusing one whose precision differs from what the file says.
     The top level holds the published model; ``program`` holds what the
-    program runs where the two differ (recorded under ``departures``)."""
+    program runs where the two differ (recorded under ``departures``).
+
+    It starts from the architecture's full-size config
+    (``repro.configs``).  Each key of :data:`PUBLISHED` that the file has
+    sets its field; then ``program.fields`` sets any other field by name
+    (expert counts, top-k, latent ranks).  A field ``ModelConfig`` does
+    not have is refused, and so is a size (any whole-number field but the
+    :data:`KNOBS`) below the full-size config's whose key (the published
+    key, or the field's own name) the file's ``reduced`` does not list."""
     from repro.configs import get_config
     prog = conf["program"]
     base = get_config(prog["arch"])
-    cfg = dataclasses.replace(
-        base, num_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"], num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        head_dim=conf["head_dim"], rope_theta=conf["rope_theta"],
-        norm_eps=conf.get("rms_norm_eps", conf.get("norm_eps")),
-        tie_embeddings=prog["tie_word_embeddings"])
+    known = {f.name for f in dataclasses.fields(base)}
+    by_key = {k: (field, conf[k]) for k, field in PUBLISHED.items()
+              if k in conf}
+    if "tie_word_embeddings" in prog:
+        by_key["program.tie_word_embeddings"] = (
+            "tie_embeddings", prog["tie_word_embeddings"])
+    for field, v in prog.get("fields", {}).items():
+        if field not in known:
+            raise ValueError(f"{prog['arch']}: program.fields sets "
+                             f"{field!r}, which ModelConfig does not have")
+        by_key[field] = (field, v)
+    reduced = set(conf.get("reduced", []))
+    for key, (field, v) in by_key.items():
+        was = getattr(base, field)
+        if (isinstance(v, int) and not isinstance(v, bool)
+                and isinstance(was, int) and v < was
+                and field not in KNOBS and key not in reduced):
+            raise ValueError(f"{prog['arch']}: {key} = {v} cuts {field} "
+                             f"from {was}, and reduced does not list "
+                             f"{key!r}")
+    cfg = dataclasses.replace(base, **{f: v for f, v in by_key.values()})
     for k in ("compute_dtype", "param_dtype"):
         if getattr(cfg, k) != prog[k]:
             raise ValueError(f"{prog['arch']}: the program runs {k} "
@@ -242,8 +288,9 @@ class Client:
     the step's decode.  Those are the head of the FIFO queue that fits
     the rows and pages free when the step began (each request reserves
     the pages of its whole trajectory, prompt plus ``max_new``); a
-    request admitted after the step's decode, into what retirements
-    freed, gets its prefill token only."""
+    request of one token retires at its admission and gives its row and
+    pages back at once.  A request admitted after the step's decode, into
+    what retirements freed, gets its prefill token only."""
 
     def __init__(self, eng, engine: Dict):
         import jax
@@ -288,12 +335,14 @@ class Client:
             if rows == 0 or self._pages(u) > pages:
                 break
             out.append(u)
-            rows -= 1
-            pages -= self._pages(u)
+            if self.recs[u].item.max_new > 1:
+                rows -= 1
+                pages -= self._pages(u)
         return out
 
     def step(self) -> bool:
-        head = self._admitted_first()
+        head = [u for u in self._admitted_first()
+                if self.recs[u].item.max_new > 1]
         first = set(head)
         before = list(self.running)
         lens = [len(self.recs[u].item.prompt) + len(self.recs[u].times)
@@ -383,9 +432,14 @@ def drive(client: Client, items, mix: Dict, seconds: float, trace_s: float,
         client.recs[it.uid] = Rec(item=it, due=t_open + it.arrival_s)
     win = None
     busy = kind == "backlog"
+    # the loop iteration in the window that ran longest beyond the sleep
+    # it asked for: that excess, its parts (loop top, submit, step, wait)
+    # and when it began
+    slowest = (0.0, 0.0, 0.0, 0.0, 0.0, t_open)
+    t_end = clock()
     counter.on = True
     while True:
-        now = clock()
+        t_top, now = t_end, clock()
         if now >= t_close:
             break
         if trace_dir and win is None and now >= t_close - trace_s:
@@ -398,18 +452,27 @@ def drive(client: Client, items, mix: Dict, seconds: float, trace_s: float,
             it = pending.pop(0)
             client.submit(it, client.recs[it.uid].due)
             busy = True
+        t_sub, stepped, asked = clock(), busy, 0.0
         if busy:
             busy = client.step()
         else:
             nxt = client.recs[pending[0].uid].due if pending else t_close
             with client._ann("bench.wait"):
-                time.sleep(max(0.0, min(nxt, t_close) - clock()))
+                asked = max(0.0, min(nxt, t_close) - clock())
+                time.sleep(asked)
+        t_end = clock()
+        if t_top >= t_open and t_end - t_top - asked > slowest[0]:
+            ran = t_end - t_sub
+            slowest = (t_end - t_top - asked, now - t_top, t_sub - now,
+                       ran if stepped else 0.0, 0.0 if stepped else ran,
+                       t_top)
     counter.on = False
     if win is not None:
         client.trace_on = False
         win.__exit__(None, None, None)
         jax.profiler.stop_trace()
-    return {"traffic": t_traffic, "open": t_open, "close": t_close}
+    return {"traffic": t_traffic, "open": t_open, "close": t_close,
+            "slowest": slowest}
 
 
 # -- correctness -----------------------------------------------------------------
@@ -439,14 +502,12 @@ def sample_finished(client: Client, k: int, seed: int) -> List[Dict]:
     return out
 
 
-def compare(conf: Dict, seed: int, sample: List[Dict], mix: Dict,
+def compare(ref, conf: Dict, seed: int, sample: List[Dict], mix: Dict,
             control: bool = False) -> Dict:
     """The compared numbers (largest over the sample) of the program
-    against the reference, and with ``control`` the same numbers of the
-    fp8 control.  Makes the weights again from the seed."""
-    import weights as weights_mod
-    ref = _reference(conf["reference"])
-    w = weights_mod.make_weights(conf, seed)
+    against the reference module ``ref``, and with ``control`` the same
+    numbers of the fp8 control.  Makes the weights again from the seed."""
+    w = ref.make_weights(conf, seed)
     T = mix["engine"]["max_ctx"]
     n_pad = mix["output_len"]["max"]
     out = {"logit_gap": 0.0, "logprob_err": 0.0, "logprob_ff_err": 0.0,
@@ -490,6 +551,7 @@ def compare(conf: Dict, seed: int, sample: List[Dict], mix: Dict,
 
 
 def _reference(name: str):
+    """The architecture's module ``bench/reference/<name>.py``."""
     path = os.path.join(BENCH, "reference", f"{name}.py")
     spec = importlib.util.spec_from_file_location(f"bench_ref_{name}", path)
     mod = importlib.util.module_from_spec(spec)
@@ -497,6 +559,8 @@ def _reference(name: str):
     return mod
 
 
+# the numbers a cell may compare; a cell compares those its limits file
+# holds, at least one
 CHECKED = ("logit_gap", "logprob_err", "logprob_ff_err")
 
 
@@ -514,6 +578,8 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     bench = bench if bench is not None else load_benchmark()
     parts = parts if parts is not None else resolve(bench, cell)
     conf, mix, limits = parts["config"], parts["mix"], parts["limits"]
+    if not any(k in limits for k in CHECKED):
+        raise ValueError(f"{cell}: its limits compare none of {CHECKED}")
     chips = parts.get("workload", {}).get("chips", 1)
     dev = check_device(chips) if need_chip else None
     import jax
@@ -522,8 +588,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     import repro.ff as ff
     from repro import obs
     from repro.serve import ServeEngine
-    import weights as weights_mod
-    import flops as flops_mod
+    arch = _reference(conf["reference"])
 
     d0 = jax.devices()[0]
     log(f"device {d0.platform} {d0.device_kind} x{len(jax.devices())}; jax "
@@ -536,7 +601,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     with ff.policy(prog["policy"]), obs.enable():
         t0 = time.perf_counter()
         counter.on = True
-        params = weights_mod.make_weights(conf, seed)
+        params = arch.make_weights(conf, seed)
         jax.block_until_ready(params)
         t_weights = time.perf_counter()
         eng = ServeEngine(params, cfg, max_batch=eng_kw["max_batch"],
@@ -563,6 +628,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
         win = drive(client, items, mix, seconds, mix.get("trace_s", 3),
                     counter, trace_dir)
     obs_close = observer.trace.now()
+    obs_at_setup = obs_open
     obs_open += (win["open"] - t_setup) * 1e6
     setup_s = win["traffic"] - T_PROCESS
     peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
@@ -585,13 +651,13 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
                                     win["close"])
     run = {
         "cell": cell, "seed": seed, "seconds": seconds, "config": conf,
-        "mix": mix, "sizes": weights_mod.sizes(conf),
+        "mix": mix, "sizes": arch.sizes(conf),
         "peaks": dev["peaks"] if dev else None,
         "kv_bytes": 2 if prog["kv_mode"] == "bf16" else 4,
         "setup_s": setup_s, "window": win, "recs": recs,
         "traced_lens": client.traced_lens,
         "engine_events": events, "obs_window": (obs_open, obs_close),
-        "trace": None, "flops": flops_mod,
+        "trace": None, "arch": arch,
     }
     lines = [f"setup {setup_s:.3f} s; window {seconds} s; steps in window "
              f"{n_steps}; requests attempted {attempted}, failed {failed}"]
@@ -601,6 +667,9 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     p99 = stats.percentile(late, 99)
     lines.append(f"generator lateness p99 "
                  f"{(p99 or 0.0) * 1e3:.3f} ms over {len(late)} submits")
+    lines.append(slowest_line(win, events,
+                              obs_at_setup + (win["slowest"][-1] - t_setup)
+                              * 1e6))
     limit = (d0.memory_stats() or {}).get("bytes_limit")
     lines.append(f"peak HBM after the window {peak} bytes (limit {limit}; "
                  f"compiled programs' scratch not counted)")
@@ -660,12 +729,16 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
         return {"metrics": metrics, "attempted": attempted,
                 "queue_first_third": float(np.mean(depth[:third] or [0])),
                 "queue_last_third": float(np.mean(depth[-third:] or [0]))}
-    cmp = compare(conf, seed, sample, mix, control=control)
-    checks = {k: {"value": cmp[k], "limit": limits[k]} for k in CHECKED}
+    cmp = compare(arch, conf, seed, sample, mix, control=control)
+    compared = [k for k in CHECKED if k in limits]
+    checks = {k: {"value": cmp[k], "limit": limits[k]} for k in compared}
     ok = (bool(sample) and failed == 0
           and all(c["value"] <= c["limit"] for c in checks.values()))
     log(f"compared {len(sample)} finished requests, {cmp['tokens']} served "
         f"tokens, against the float32 reference")
+    for k in CHECKED:
+        if k not in limits:
+            log(f"reading {k} = {cmp[k]!r} (not compared in this cell)")
     for k, c in checks.items():
         log(f"check {k} = {c['value']!r} (limit {c['limit']!r})")
     result = {"correct": ok, "attempted": attempted, "failed": failed,
@@ -674,14 +747,37 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = breakdown
     if control:
         ctl = {k: {"value": cmp["control"][k], "limit": limits[k]}
-               for k in CHECKED}
+               for k in compared}
         result["control"] = {
             "correct": all(c["value"] <= c["limit"] for c in ctl.values()),
-            "tokens": cmp["control"]["tokens"], "checks": ctl}
+            "tokens": cmp["control"]["tokens"], "checks": ctl,
+            "readings": {k: cmp["control"][k] for k in CHECKED},
+            "program": {k: cmp[k] for k in CHECKED}}
         for k, c in ctl.items():
             log(f"control {k} = {c['value']!r} (limit {c['limit']!r})")
     result["checks"] = checks
     return result
+
+
+def slowest_line(win: Dict, events: List[Dict], obs_at: float) -> str:
+    """The window's loop iteration that ran longest beyond the sleep it
+    asked for, its parts, and the engine spans that ran in it (``obs_at``:
+    its start on the recorder's clock)."""
+    over, top, sub, step, wait, at = win["slowest"]
+    lo, hi = obs_at, obs_at + (top + sub + step + wait) * 1e6
+    inside: Dict[str, float] = {}
+    for e in events:
+        if e.get("ph") == "X" and e["name"].startswith("serve."):
+            o = min(hi, e["ts"] + e["dur"]) - max(lo, e["ts"])
+            if o > 0:
+                inside[e["name"]] = inside.get(e["name"], 0.0) + o
+    spans = ", ".join(f"{k} {v / 1e3:.3f} ms" for k, v in sorted(
+        inside.items(), key=lambda kv: -kv[1])[:6])
+    return (f"slowest loop iteration in the window: {over * 1e3:.3f} ms "
+            f"beyond its asked sleep, at "
+            f"+{at - win['open']:.3f} s: loop top {top * 1e3:.3f} ms, "
+            f"submit {sub * 1e3:.3f} ms, step {step * 1e3:.3f} ms, wait "
+            f"{wait * 1e3:.3f} ms; engine spans in it: {spans or 'none'}")
 
 
 def _xplane(directory: Optional[str]) -> Optional[str]:

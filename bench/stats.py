@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import math
+import re
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 
@@ -18,6 +19,14 @@ def percentile(values: Iterable[float], q: float) -> Optional[float]:
     return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
 
 
+def name_percentile(name: str, default: float = 50.0) -> float:
+    """The NN of a metric name's ``pNN`` part, after a dot or between
+    underscores (``prefill_ms.p90.prefill`` and ``ttft_p90_ms`` read 90),
+    or ``default`` where it has none."""
+    m = re.search(r"(?:\.|_)p(\d+)(?=\.|_|$)", name)
+    return float(m.group(1)) if m else default
+
+
 def ttfts(due: Sequence[float], first: Sequence[Optional[float]],
           close: float) -> List[float]:
     """Time to first token of every request due before ``close``.  A
@@ -30,6 +39,15 @@ def ttfts(due: Sequence[float], first: Sequence[Optional[float]],
             continue
         out.append((f if f is not None and f <= close else close) - d)
     return out
+
+
+def window_ttfts(recs, window) -> List[float]:
+    """:func:`ttfts` of the client's records due in the window
+    (``open``, ``close``)."""
+    due = [r for r in recs if window["open"] <= r.due < window["close"]]
+    return ttfts([r.due for r in due],
+                 [r.times[0] if r.times else None for r in due],
+                 window["close"])
 
 
 def gaps_in_window(times: Sequence[float], open_: float,

@@ -53,6 +53,39 @@ def test_output_rate_reader():
     assert mod.read(run, "output_tokens_per_s") == pytest.approx(5 / 3)
 
 
+def test_ttft_p90_ms_reader():
+    import types
+    import run
+    # due times and first tokens: two due before the window, one after
+    # its close, one served after the close and one never served
+    due = [0.5, 1.0, 1.5, 2.0, 2.5, 3.0, 3.5, 4.5, 5.0]
+    first = [0.6, 1.2, 1.6, 2.4, 2.6, 3.1, None, 5.5, 5.1]
+    recs = [types.SimpleNamespace(due=d, times=[] if f is None else [f])
+            for d, f in zip(due, first)]
+    run_ = {"recs": recs, "window": {"open": 1.0, "close": 5.0}}
+    # in the window: 0.2, 0.1, 0.4, 0.1, 0.1, 1.5 (waited to the close),
+    # 0.5 (served after the close: counts to the close)
+    waits = sorted([0.2, 0.1, 0.4, 0.1, 0.1, 1.5, 0.5])
+    want = 1e3 * (waits[5] + 0.4 * (waits[6] - waits[5]))
+    # one reader, bench/metrics/ttft_ms.py, serves both names
+    read = run.load_reader("ttft_p90_ms")
+    assert read.__module__ == run.load_reader("ttft_ms.p90").__module__ \
+        == "bench_metric_ttft_ms"
+    assert read(run_, "ttft_p90_ms") == pytest.approx(want)
+    assert read(run_, "ttft_ms.p90") == pytest.approx(want)
+    assert read({"recs": [], "window": run_["window"]},
+                "ttft_p90_ms") is None
+
+
+@pytest.mark.parametrize("name,q", [
+    ("prefill_ms.p90", 90), ("queue_wait_ms.p90.prefill", 90),
+    ("prefill_ms.p50.prefill", 50), ("queue_wait_ms", 50),
+    ("prefill_ms.prefill", 50), ("ttft_p90_ms", 90), ("itl_p95_ms", 95),
+    ("step_host_ms.prefill", 50)])
+def test_percentile_from_a_metric_name(name, q):
+    assert stats.name_percentile(name) == q
+
+
 def test_union_and_idle_gaps():
     iv = [(0.0, 1.0), (0.5, 2.0), (3.0, 4.0), (3.5, 3.6), (6.0, 7.0)]
     assert stats.union_length(iv, 0.0, 10.0) == pytest.approx(4.0)

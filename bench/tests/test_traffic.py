@@ -13,7 +13,7 @@ import traffic
 BIG = 2 ** 31 + 2 ** 33 + 12345      # wider than 32 signed bits
 
 
-@pytest.mark.parametrize("mix", ["chat", "offline"])
+@pytest.mark.parametrize("mix", ["chat", "offline", "prefill"])
 def test_same_seed_same_requests(mix):
     m = traffic.load_mix(mix)
     a = traffic.generate(m, BIG, 30, 49155)
@@ -24,14 +24,16 @@ def test_same_seed_same_requests(mix):
         np.testing.assert_array_equal(x.prompt, y.prompt)
 
 
-@pytest.mark.parametrize("mix", ["chat", "offline"])
+@pytest.mark.parametrize("mix", ["chat", "offline", "prefill"])
 def test_seeds_change_order_not_work(mix):
     m = traffic.load_mix(mix)
     a = traffic.generate(m, 1, 30, 49155)
     b = traffic.generate(m, BIG, 30, 49155)
     assert sorted(len(x.prompt) for x in a) == sorted(len(x.prompt) for x in b)
     assert sorted(x.max_new for x in a) == sorted(x.max_new for x in b)
-    assert [len(x.prompt) for x in a] != [len(x.prompt) for x in b]
+    assert [x.max_new for x in a] != [x.max_new for x in b]
+    if "schedule_seed" not in m.get("arrival", {}):
+        assert [len(x.prompt) for x in a] != [len(x.prompt) for x in b]
     # the gaps, the one from the last arrival to the close included
     ga = np.diff([x.arrival_s for x in a] + [30.0])
     gb = np.diff([x.arrival_s for x in b] + [30.0])
@@ -60,6 +62,58 @@ def test_chat_lengths_and_arrivals():
     assert inside == round(m["arrival"]["rate_per_s"] * 30)
     med = np.median([len(x.prompt) for x in items])
     assert 128 <= med <= 256
+
+
+def test_prefill_lengths_and_arrivals():
+    m = traffic.load_mix("prefill")
+    items = traffic.generate(m, 11, 30, 49155)
+    lens = [len(x.prompt) for x in items]
+    # every bucket that set-up warms is sent, in equal shares
+    assert set(lens) == set(traffic.prompt_lengths(m)) == {
+        1024, 1280, 1536, 1792, 2040}
+    counts = [lens.count(v) for v in set(lens)]
+    assert max(counts) - min(counts) <= 2
+    assert {x.max_new for x in items} == {1, 2, 3, 4}
+    assert all(len(x.prompt) + x.max_new <= m["engine"]["max_ctx"]
+               for x in items)
+    inside = sum(1 for x in items if 0 <= x.arrival_s < 30)
+    assert inside == round(m["arrival"]["rate_per_s"] * 30) >= 150
+
+
+@pytest.mark.parametrize("mix", ["chat", "prefill"])
+def test_a_schedule_seed_fixes_the_arrival_trace(mix):
+    # with a schedule_seed every run replays one trace (arrival times and
+    # prompt lengths); the run's seed still draws the answer lengths'
+    # order and the tokens.  Without one the seed orders everything.
+    m = traffic.load_mix(mix)
+    m["arrival"].setdefault("schedule_seed", 15)
+    a, b = (traffic.generate(m, s, 30, 49155) for s in (5, BIG))
+    assert [x.arrival_s for x in a] == [x.arrival_s for x in b]
+    assert [len(x.prompt) for x in a] == [len(x.prompt) for x in b]
+    assert [x.max_new for x in a] != [x.max_new for x in b]
+    assert any(not np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
+    # another schedule_seed is another trace of the same work
+    c = traffic.generate(dict(m, arrival=dict(m["arrival"],
+                                              schedule_seed=16)),
+                         5, 30, 49155)
+    assert [x.arrival_s for x in c] != [x.arrival_s for x in a]
+    assert sorted(len(x.prompt) for x in c) == sorted(len(x.prompt)
+                                                      for x in a)
+    free = dict(m, arrival={k: v for k, v in m["arrival"].items()
+                            if k != "schedule_seed"})
+    f1, f2 = (traffic.generate(free, s, 30, 49155) for s in (5, BIG))
+    assert [x.arrival_s for x in f1] != [x.arrival_s for x in f2]
+
+
+def test_a_schedule_seed_leaves_other_mixes_alone():
+    # the chat mix names none: its requests are those the seed alone draws
+    m = traffic.load_mix("chat")
+    assert "schedule_seed" not in m["arrival"]
+    rng = np.random.default_rng(BIG)
+    n = traffic.stretches(m, 30)[0][2]
+    want = -6.0 + traffic.arrivals(m, n, 6.0, rng)
+    got = [x.arrival_s for x in traffic.generate(m, BIG, 30, 49155)][:n]
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-12)
 
 
 def test_offline_backlog():

@@ -13,7 +13,11 @@ Kinds of mix:
   ``round(rate * seconds)`` spanning ``[0, seconds)``, each with
   exponential gaps scaled to span its stretch exactly and lengths from
   its own strata; time 0 is the window's opening.  So the window holds
-  the same requests, by number and by size, for every seed.
+  the same requests, by number and by size, for every seed.  Where the
+  ``arrival`` block names a ``schedule_seed``, the schedule (the order of
+  the gaps and of the prompt lengths) is drawn from it and not from the
+  run's seed: every run replays one arrival trace, and the run's seed
+  draws the order of the answer lengths and the prompt tokens.
 * ``backlog``: offline batch work, all queued before the window opens:
   ``max_batch + ceil(backlog_per_s * seconds)`` requests.
 """
@@ -116,10 +120,13 @@ def generate(mix: Dict, seed: int, seconds: float,
              vocab_size: int) -> List[Item]:
     """The cell's requests for ``seed``, in arrival order."""
     rng = np.random.default_rng(seed)
+    fixed = mix.get("arrival", {}).get("schedule_seed")
+    sched = rng if fixed is None else np.random.default_rng(fixed)
     t, plen, olen = [], [], []
     for start, span, n in stretches(mix, seconds):
-        t.append(start + arrivals(mix, n, span, rng))
-        plen.append(quantile(mix["prompt_len"], rng.permutation(strata(n))))
+        t.append(start + arrivals(mix, n, span, sched))
+        plen.append(quantile(mix["prompt_len"],
+                             sched.permutation(strata(n))))
         olen.append(quantile(mix["output_len"], rng.permutation(strata(n))))
     t, plen, olen = (np.concatenate(x) for x in (t, plen, olen))
     return [Item(uid=i, arrival_s=float(t[i]),

@@ -1,8 +1,9 @@
 """Random weights from the seed, made on the device in one jitted call.
 
-The benchmark makes the weights, not the program, so that the plain
-reference can make the same ones again without taking anything the
-program made.  The pytree is the layout the serving engine reads
+The dense GQA decoder's weights (``bench/reference/dense_gqa.py`` hands
+them to the harness).  The benchmark makes the weights, not the program,
+so that the plain reference can make the same ones again without taking
+anything the program made.  The pytree is the layout the serving engine reads
 (``embed.tok``, ``embed.unembed``, a layer stack scanned on axis 0 with
 ``ln1``/``ln2``, ``attn.{wq,wk,wv,wo}``, ``ffn.{w_gate,w_up,w_down}``,
 and ``final_norm``).  Matrices are normal with std ``1/sqrt(fan_in)`` and
