@@ -152,6 +152,8 @@ def param_spec(path, leaf, cfg: ModelConfig, mesh: Mesh) -> P:
 
     # MoE expert tensors: path contains an 'ffn'/'ffn_moe'/'shared' marker
     in_moe = any(getattr(k, "key", None) in ("ffn", "ffn_moe") for k in path)
+    in_moe = in_moe and not any(
+        getattr(k, "key", None) == "dense_layers" for k in path)
     in_shared = any(getattr(k, "key", None) == "shared" for k in path)
     if in_moe and not in_shared and name in ("w_gate", "w_up", "w_down") \
             and cfg.moe_num_experts and cfg.moe_num_experts % tp == 0:

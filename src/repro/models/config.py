@@ -42,6 +42,18 @@ class ModelConfig:
     moe_shared_experts: int = 0              # deepseek-style shared experts
     moe_capacity_factor: float = 1.25
     moe_every: int = 1                       # MoE FFN every k-th layer
+    first_k_dense_replace: int = 0           # leading layers with a dense FFN
+    # routing (deepseek's MoEGate): softmax over all experts; with
+    # n_group > 1 only the experts of the topk_group best groups (a group
+    # scored by its best expert) compete for the top-k
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_norm_topk_prob: bool = True          # renormalise the k gates
+    moe_routed_scaling: float = 1.0          # else scale them by this
+    # the experts this device holds (expert parallelism): experts
+    # [offset, offset + held) of every MoE layer; 0 = all of them
+    moe_held_experts: int = 0
+    moe_held_offset: int = 0
 
     # MLA (deepseek)
     use_mla: bool = False
@@ -50,6 +62,15 @@ class ModelConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+    # YaRN rope_scaling (deepseek); rope_scaling "" is plain RoPE
+    rope_scaling: str = ""
+    yarn_factor: float = 1.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM (mamba2 / hybrid)
     ssm_state: int = 128
@@ -83,6 +104,12 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // max(self.num_kv_heads, 1)
+
+    @property
+    def moe_held(self) -> Tuple[int, int]:
+        """``(offset, count)`` of the routed experts held here."""
+        return self.moe_held_offset, (self.moe_held_experts
+                                      or self.moe_num_experts)
 
     @property
     def ssm_d_inner(self) -> int:

@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 import repro.ff as ff
@@ -96,13 +97,66 @@ def rope_freqs(head_dim: int, theta: float) -> Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: (..., S, H, hd) ; positions: broadcastable to (..., S)."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature factor (DeepSeek-V2's
+    ``yarn_get_mscale``): ``0.1 * mscale * ln(scale) + 1`` above scale 1."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(dim: int, cfg: ModelConfig) -> Array:
+    """YaRN inverse frequencies (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``):
+    interpolated (divided by ``yarn_factor``) below the correction range of
+    ``yarn_beta_fast`` .. ``yarn_beta_slow`` rotations over the original
+    context, extrapolated (plain RoPE) above it, linearly ramped between."""
+    base, factor = cfg.rope_theta, cfg.yarn_factor
+    orig = cfg.yarn_original_max_position
+
+    def corr_dim(rot):
+        return dim * math.log(orig / (rot * 2 * math.pi)) / (2 * math.log(base))
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp                   # 1: extrapolate, 0: interpolate
+    return jnp.asarray((extra / factor) * (1 - keep) + extra * keep,
+                       jnp.float32)
+
+
+def rope_for(dim: int, cfg: ModelConfig) -> Tuple[Array, float]:
+    """``(inverse frequencies, cos/sin scale)`` of a rotary slice of
+    ``dim`` columns under the config's ``rope_scaling``."""
+    if cfg.rope_scaling == "yarn":
+        scale = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+                 / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+        return yarn_freqs(dim, cfg), scale
+    if cfg.rope_scaling:
+        raise ValueError(f"rope_scaling {cfg.rope_scaling!r}: '' | 'yarn'")
+    return rope_freqs(dim, cfg.rope_theta), 1.0
+
+
+def softmax_mscale(cfg: ModelConfig) -> float:
+    """The factor on the softmax scale: YaRN's ``mscale_all_dim`` squared."""
+    if cfg.rope_scaling == "yarn" and cfg.yarn_mscale_all_dim:
+        return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return 1.0
+
+
+def apply_rope(x: Array, positions: Array, theta: float,
+               freqs: Optional[Array] = None, scale: float = 1.0) -> Array:
+    """x: (..., S, H, hd) ; positions: broadcastable to (..., S).
+    Rotate-half convention; ``freqs`` (hd/2,) replaces the plain
+    frequencies of ``theta`` (YaRN), ``scale`` multiplies cos and sin."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(angles)[..., None, :]                  # (..., S, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -44,7 +44,8 @@ def _cdtype(cfg: ModelConfig):
 # parameter init
 # ===========================================================================
 
-def _layer_params(key, cfg: ModelConfig, layer_idx: int) -> Params:
+def _layer_params(key, cfg: ModelConfig, layer_idx: int,
+                  moe: bool = True) -> Params:
     """One decoder layer (used vmapped over layers for dense stacks)."""
     k1, k2 = jax.random.split(key)
     p: Params = {"ln1": jnp.ones((cfg.d_model,), jnp.float32),
@@ -53,7 +54,7 @@ def _layer_params(key, cfg: ModelConfig, layer_idx: int) -> Params:
         p["attn"] = mla.mla_params(k1, cfg)
     else:
         p["attn"] = attn_params(k1, cfg)
-    if cfg.moe_num_experts and (layer_idx % cfg.moe_every == 0):
+    if moe and cfg.moe_num_experts and (layer_idx % cfg.moe_every == 0):
         p["ffn"] = moe_lib.moe_params(k2, cfg)
     else:
         p["ffn"] = mlp_params(k2, cfg)
@@ -61,12 +62,27 @@ def _layer_params(key, cfg: ModelConfig, layer_idx: int) -> Params:
 
 
 def _stacked_layers(key, cfg: ModelConfig) -> Params:
-    """Stack identical-structure layers along axis 0 for scan."""
+    """Stack identical-structure layers along axis 0 for scan: ``layers``,
+    and before them ``dense_layers``, the ``first_k_dense_replace``
+    leading layers whose FFN is the dense MLP."""
+    n_dense = cfg.first_k_dense_replace
     keys = jax.random.split(key, cfg.num_layers)
     if cfg.moe_num_experts and cfg.moe_every != 1:
         raise ValueError("interleaved dense/MoE stacks use the hybrid path")
     init_one = functools.partial(_layer_params, cfg=cfg, layer_idx=0)
-    return jax.vmap(init_one)(keys)
+    out = {"layers": jax.vmap(init_one)(keys[n_dense:])}
+    if n_dense:
+        out["dense_layers"] = jax.vmap(functools.partial(
+            init_one, moe=False))(keys[:n_dense])
+    return out
+
+
+def layer_stacks(params: Params, cfg: ModelConfig):
+    """The decoder's layer stacks in order, each ``(name, first layer)``:
+    ``dense_layers`` (the leading dense-FFN layers) when the config has
+    them, then ``layers``."""
+    n_dense = cfg.first_k_dense_replace if "dense_layers" in params else 0
+    return ([("dense_layers", 0)] if n_dense else []) + [("layers", n_dense)]
 
 
 def init_params(cfg: ModelConfig, key) -> Params:
@@ -75,7 +91,7 @@ def init_params(cfg: ModelConfig, key) -> Params:
                       "final_norm": jnp.ones((cfg.d_model,), jnp.float32)}
 
     if cfg.family in ("dense", "moe", "vlm"):
-        params["layers"] = _stacked_layers(kl, cfg)
+        params.update(_stacked_layers(kl, cfg))
 
     elif cfg.family == "ssm":
         keys = jax.random.split(kl, cfg.num_layers)
@@ -143,6 +159,9 @@ def init_params(cfg: ModelConfig, key) -> Params:
     if cfg.family == "vlm":
         params["patch_proj"] = jnp.eye(cfg.d_model, dtype=jnp.float32)
 
+    pdt = jnp.dtype(cfg.param_dtype)
+    if pdt != jnp.float32:          # parameters stored in a narrower dtype
+        params = jax.tree_util.tree_map(lambda w: w.astype(pdt), params)
     return params
 
 
@@ -228,7 +247,11 @@ def _run_stack(params: Params, x: Array, cfg: ModelConfig,
 
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
-    (x, aux), _ = lax.scan(body, (x, jnp.float32(0)), params["layers"])
+    aux = jnp.float32(0)
+    stacks = layer_stacks(params, cfg) if cfg.family in (
+        "dense", "moe", "vlm") else [("layers", 0)]
+    for name, _ in stacks:
+        (x, aux), _ = lax.scan(body, (x, aux), params[name])
     return x, aux
 
 
@@ -449,6 +472,32 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     raise ValueError(cfg.family)
 
 
+def _ffn_serve(p: Params, z: Array, cfg: ModelConfig,
+               policy: PrecisionPolicy) -> Array:
+    """A layer's FFN when serving: the dense MLP, or the dropless MoE
+    layer over the held experts (``moe.moe_serve``)."""
+    if "router" in p:
+        return moe_lib.moe_serve(p, z, cfg, ff_math=policy.ff_math)[0]
+    return mlp_apply(p, z, ff_math=policy.ff_math)
+
+
+def _scan_stacks(body, x: Array, params: Params, cache: Params,
+                 cfg: ModelConfig) -> Tuple[Array, Params]:
+    """Scan ``body`` over each layer stack with its slice of the cache's
+    ``layers`` (one entry per decoder layer, in order)."""
+    outs = []
+    for name, first in layer_stacks(params, cfg):
+        n = jax.tree_util.tree_leaves(params[name])[0].shape[0]
+        part = jax.tree_util.tree_map(lambda c: c[first:first + n],
+                                      cache["layers"])
+        x, new = lax.scan(body, x, (params[name], part))
+        outs.append(new)
+    cache = dict(cache)
+    cache["layers"] = outs[0] if len(outs) == 1 else jax.tree_util.tree_map(
+        lambda *cs: jnp.concatenate(cs, axis=0), *outs)
+    return x, cache
+
+
 def prefill(params: Params, batch: Dict[str, Array], cfg: ModelConfig,
             cache: Params, policy: Optional[PrecisionPolicy] = None
             ) -> Tuple[Array, Params]:
@@ -485,17 +534,11 @@ def prefill(params: Params, batch: Dict[str, Array], cfg: ModelConfig,
             h = h + a
             z = rms_norm(h, lp["ln2"], cfg.norm_eps,
                          ff_stats=policy.ff_reductions)
-            if "router" in lp["ffn"]:
-                f, _ = moe_lib.moe_apply(lp["ffn"], z, cfg,
-                                         ff_math=policy.ff_math)
-            else:
-                f = mlp_apply(lp["ffn"], z, ff_math=policy.ff_math)
-            return h + f, lcache
+            return h + _ffn_serve(lp["ffn"], z, cfg, policy), lcache
 
         if cfg.remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        x, new_lcache = lax.scan(body, x, (params["layers"], cache["layers"]))
-        cache = {"layers": new_lcache}
+        x, cache = _scan_stacks(body, x, params, cache, cfg)
 
     elif cfg.family == "ssm":
         def body(carry, scanned):
@@ -537,8 +580,7 @@ def prefill(params: Params, batch: Dict[str, Array], cfg: ModelConfig,
                 z = rms_norm(h, lp["ln2"], cfg.norm_eps,
                              ff_stats=policy.ff_reductions)
                 if "ffn_moe" in lp:
-                    f, _ = moe_lib.moe_apply(lp["ffn_moe"], z, cfg,
-                                             ff_math=policy.ff_math)
+                    f = _ffn_serve(lp["ffn_moe"], z, cfg, policy)
                 else:
                     f = mlp_apply(lp["ffn_mlp"], z,
                                   ff_math=policy.ff_math)
@@ -636,16 +678,9 @@ def decode_step(params: Params, token: Array, pos: Array, cache: Params,
             h = h + a
             z = rms_norm(h, lp["ln2"], cfg.norm_eps,
                          ff_stats=policy.ff_reductions)
-            if "router" in lp["ffn"]:
-                f, _ = moe_lib.moe_apply(lp["ffn"], z, cfg,
-                                         ff_math=policy.ff_math)
-            else:
-                f = mlp_apply(lp["ffn"], z, ff_math=policy.ff_math)
-            return h + f, lcache
+            return h + _ffn_serve(lp["ffn"], z, cfg, policy), lcache
 
-        x, new_lcache = lax.scan(body, x, (params["layers"], cache["layers"]))
-        cache = dict(cache)
-        cache["layers"] = new_lcache
+        x, cache = _scan_stacks(body, x, params, cache, cfg)
 
     elif cfg.family == "ssm":
         def body(carry, scanned):
@@ -683,8 +718,7 @@ def decode_step(params: Params, token: Array, pos: Array, cache: Params,
                 z = rms_norm(h, lp["ln2"], cfg.norm_eps,
                              ff_stats=policy.ff_reductions)
                 if "ffn_moe" in lp:
-                    f, _ = moe_lib.moe_apply(lp["ffn_moe"], z, cfg,
-                                             ff_math=policy.ff_math)
+                    f = _ffn_serve(lp["ffn_moe"], z, cfg, policy)
                 else:
                     f = mlp_apply(lp["ffn_mlp"], z,
                                   ff_math=policy.ff_math)

@@ -45,7 +45,7 @@ from typing import Dict, Optional, Sequence, Tuple
 __all__ = ["DECODE_PARTS", "part_of", "program_parts"]
 
 #: the named scopes of the serving engine's decode program
-DECODE_PARTS = ("cast", "attn", "kv", "mlp", "head", "sample")
+DECODE_PARTS = ("cast", "attn", "kv", "mlp", "moe", "head", "sample")
 
 _MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s*\(.*\{\s*$")

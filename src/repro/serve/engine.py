@@ -86,6 +86,7 @@ oracle (gated by ``benchmarks/table_serving.py``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple
@@ -99,8 +100,10 @@ from repro import obs as obs_mod
 from repro.core.policy import PrecisionPolicy
 from repro.ff.guard import FFGuardWarning, health_mask, report_violation
 from repro.ff.scope import resolve_policy
-from repro.models import init_cache
+from repro.models import init_cache, mla
 from repro.models.config import ModelConfig
+from repro.models.model import layer_stacks
+from repro.models.moe import moe_serve
 from repro.models.layers import (apply_rope, cast_weight, decode_attention,
                                  mlp_apply, rms_norm, embed_apply,
                                  unembed_apply)
@@ -167,18 +170,36 @@ class GenResult:
     detail: str = ""
 
 
+_FAMILIES = ('"dense" (GQA attention, dense FFN) or "moe" (MLA attention, '
+             'routed experts in every layer after first_k_dense_replace)')
+
+
 def _check_cfg(cfg: ModelConfig) -> None:
+    if cfg.family == "moe":
+        if not cfg.use_mla:
+            raise UnsupportedModelError(
+                "use_mla", False, 'use_mla=True with family="moe" (the '
+                'engine pages GQA K/V only for dense stacks)')
+        if not cfg.moe_num_experts:
+            raise UnsupportedModelError(
+                "moe_num_experts", 0, 'moe_num_experts > 0 with '
+                'family="moe"')
+        if cfg.moe_every != 1:
+            raise UnsupportedModelError(
+                "moe_every", cfg.moe_every, "moe_every=1 (interleaved "
+                "dense and MoE layers are the hybrid family's)")
+        return
     if cfg.family != "dense":
-        raise UnsupportedModelError("family", cfg.family,
-                                    '"dense" (GQA decoder stack)')
+        raise UnsupportedModelError("family", cfg.family, _FAMILIES)
     if cfg.use_mla:
         raise UnsupportedModelError(
-            "use_mla", True, "use_mla=False — the MLA latent cache is not "
-            "paged yet (ROADMAP item 1)")
+            "use_mla", True, 'use_mla=False with family="dense"; MLA is '
+            'served with family="moe"')
     if cfg.moe_num_experts:
         raise UnsupportedModelError(
             "moe_num_experts", cfg.moe_num_experts,
-            "moe_num_experts=0 (dense FFN)")
+            'moe_num_experts=0 with family="dense"; routed experts are '
+            'served with family="moe"')
 
 
 def _bytes_by_dtype(leaves) -> Dict[str, int]:
@@ -342,10 +363,9 @@ class ServeEngine:
         pages_per_seq = -(-max_ctx // page_size)
         if num_pages is None:
             num_pages = max_batch * pages_per_seq
-        self.kv = PagedKVCache(
-            cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim,
-            num_pages=num_pages, page_size=page_size, max_seqs=max_batch,
-            max_ctx=max_ctx, kv_mode=kv_mode)
+        self.kv = PagedKVCache.for_config(
+            cfg, num_pages=num_pages, page_size=page_size,
+            max_seqs=max_batch, max_ctx=max_ctx, kv_mode=kv_mode)
         self.queue: List[Dict[str, Any]] = []   # {"req", "t_sub", "step_sub"}
         self.results: Dict[int, GenResult] = {}
         # slot -> in-flight request bookkeeping (None = free row)
@@ -389,27 +409,52 @@ class ServeEngine:
         ff_pages = kv.kv_mode == "ff_bf16"
         probe = self.guard_mode != "off"
 
+        def page_write_read(pl, base, new, at, wpage, woff, gidx):
+            """Write each row's new entry ``new`` (B, *tail) of plane
+            ``base`` at its page/offset (``at``: the index of the layer
+            within ``pl``, () when ``pl`` is one layer's slice), and read
+            every row's pages back: (B, npg * ps, *tail), merged to f32
+            in FF mode."""
+            B = new.shape[0]
+            if ff_pages:
+                hi, lo = ff_split(new)
+                pl[f"{base}_hi"] = pl[f"{base}_hi"].at[
+                    at + (wpage, woff)].set(hi, mode="drop")
+                pl[f"{base}_lo"] = pl[f"{base}_lo"].at[
+                    at + (wpage, woff)].set(lo, mode="drop")
+                merged = ff_merge(pl[f"{base}_hi"][at + (gidx,)],
+                                  pl[f"{base}_lo"][at + (gidx,)])
+            else:
+                pdt = pl[base].dtype
+                pl[base] = pl[base].at[at + (wpage, woff)].set(
+                    new.astype(pdt), mode="drop")
+                merged = pl[base][at + (gidx,)]
+            return merged.reshape((B, npg * ps) + new.shape[1:])
+
         def step_decode(params, token, lens, bt, active, planes):
             """token: (B,1) int32; lens: (B,) tokens already cached;
             bt: (B, npg) page table (-1 empty); active: (B,) bool;
-            planes: dict of (L, NP, ps, KV, hd).  Returns (next greedy
+            planes: dict of (L, NP, ps, *tail).  Returns (next greedy
             token (B,), its f32 and FF (hi, lo) logprobs, a per-row guard
-            flag (constant False with the probe off), updated planes) —
+            flag (constant False with the probe off), updated planes,
+            and for a routed-expert model a seventh output, the (L_moe,
+            held) int32 pairs routed to each held expert of each MoE
+            layer) —
             argmax and BOTH scoring tiers run inside the one jitted step,
             so per decode step the host sees four (B,) vectors (plus the
             flag), not the (B, V) logits.  Math per active row is exactly
-            the ``model.decode_step`` dense body at that row's position.
+            the ``model.decode_step`` body at that row's position.
 
             Named scopes split the program into the parts of
             ``repro.obs.DECODE_PARTS``: ``cast`` (weights to the compute
             dtype, ``cast_weight``: empty, as the engine serves weights
             already in it), ``attn``, ``kv`` (page write, gather, pool
-            update), ``mlp``, ``head`` and ``sample``; the token lookup
-            is ``embed``, which is no part."""
+            update), ``mlp`` (the dense FFN), ``moe`` (gate, sort,
+            grouped expert products, combine, shared experts), ``head``
+            and ``sample``; the token lookup is ``embed``, which is no
+            part."""
             dt = jnp.dtype(cfg.compute_dtype)
             B = token.shape[0]
-            H, KVh = cfg.num_heads, cfg.num_kv_heads
-            hd = cfg.resolved_head_dim
             NP = next(iter(planes.values())).shape[1]
             with jax.named_scope("embed"):
                 x = embed_apply(params["embed"], token, dt)
@@ -421,6 +466,42 @@ class ServeEngine:
                 woff = lens % ps
                 gidx = jnp.maximum(bt, 0)      # gather table (garbage rows
             posv = lens[:, None]               # are masked by lens later)
+            rw = functools.partial(page_write_read, wpage=wpage, woff=woff,
+                                   gidx=gidx)
+            layers = latent_layers if kv.kind == "mla" else gqa_layers
+            x, bad, planes, pairs = layers(params, x, planes, posv, lens,
+                                           active, rw, dt)
+            with jax.named_scope("head"):
+                x = rms_norm(x, params["final_norm"], cfg.norm_eps,
+                             ff_stats=policy.ff_reductions)
+                logits = unembed_apply(params["embed"], x, cfg,
+                                       ff_math=policy.ff_math)[:, 0]
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                lp = token_logprob(logits, nxt, policy)
+                lp_ff = token_logprob_ff(logits, nxt)
+                if probe:
+                    # score health: non-finite f32 score, or an FF score
+                    # pair that is non-finite / unnormalized
+                    # (|lo| > ulp(hi)/2)
+                    bad = bad | ~jnp.isfinite(lp) | ~health_mask(lp_ff)
+            out = (nxt, lp, lp_ff.hi, lp_ff.lo, bad, planes)
+            return out if pairs is None else out + (pairs,)
+
+        def flag_nonfinite(bad, *news):
+            # a non-finite new cache entry in any layer poisons the row's
+            # cache for every later step: flag at the source
+            for new in news:
+                bad = bad | ~jnp.isfinite(new.astype(jnp.float32)).all(
+                    axis=tuple(range(1, new.ndim)))
+            return bad
+
+        def gqa_layers(params, x, planes, posv, lens, active, rw, dt):
+            """The dense GQA stack: one scan, each layer's planes sliced
+            in and out of it."""
+            B = x.shape[0]
+            H, KVh = cfg.num_heads, cfg.num_kv_heads
+            hd = cfg.resolved_head_dim
 
             def body(carry, scanned):
                 h, bad = carry
@@ -438,31 +519,10 @@ class ServeEngine:
                     q = apply_rope(q, posv, cfg.rope_theta)
                     k = apply_rope(k, posv, cfg.rope_theta)
                     if probe:
-                        # non-finite new K/V in this layer poisons the
-                        # row's cache for every later step: flag at the
-                        # source
-                        bad = bad | ~jnp.isfinite(
-                            k.astype(jnp.float32)).all(axis=(1, 2, 3))
-                        bad = bad | ~jnp.isfinite(
-                            v.astype(jnp.float32)).all(axis=(1, 2, 3))
-                gathered = {}
+                        bad = flag_nonfinite(bad, k, v)
                 with jax.named_scope("kv"):
-                    for base, new in (("k", k), ("v", v)):
-                        if ff_pages:
-                            hi, lo = ff_split(new[:, 0])
-                            pl[f"{base}_hi"] = pl[f"{base}_hi"].at[
-                                wpage, woff].set(hi, mode="drop")
-                            pl[f"{base}_lo"] = pl[f"{base}_lo"].at[
-                                wpage, woff].set(lo, mode="drop")
-                            merged = ff_merge(pl[f"{base}_hi"][gidx],
-                                              pl[f"{base}_lo"][gidx])
-                        else:
-                            pdt = pl[base].dtype
-                            pl[base] = pl[base].at[wpage, woff].set(
-                                new[:, 0].astype(pdt), mode="drop")
-                            merged = pl[base][gidx]
-                        gathered[base] = merged.reshape(
-                            B, npg * ps, KVh, hd)
+                    gathered = {base: rw(pl, base, new[:, 0], ())
+                                for base, new in (("k", k), ("v", v))}
                 with jax.named_scope("attn"):
                     o = decode_attention(q, gathered["k"], gathered["v"],
                                          lens + 1, impl=policy.attention)
@@ -479,22 +539,62 @@ class ServeEngine:
                 body, (x, bad0),
                 (params["layers"],) + tuple(
                     planes[n] for n in sorted(planes)))
-            with jax.named_scope("head"):
-                x = rms_norm(x, params["final_norm"], cfg.norm_eps,
-                             ff_stats=policy.ff_reductions)
-                logits = unembed_apply(params["embed"], x, cfg,
-                                       ff_math=policy.ff_math)[:, 0]
-            with jax.named_scope("sample"):
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                lp = token_logprob(logits, nxt, policy)
-                lp_ff = token_logprob_ff(logits, nxt)
-                if probe:
-                    # score health: non-finite f32 score, or an FF score
-                    # pair that is non-finite / unnormalized
-                    # (|lo| > ulp(hi)/2)
-                    bad = bad | ~jnp.isfinite(lp) | ~health_mask(lp_ff)
-            return (nxt, lp, lp_ff.hi, lp_ff.lo, bad,
-                    dict(zip(sorted(planes), updated)))
+            return x, bad, dict(zip(sorted(planes), updated)), None
+
+        def latent_layers(params, x, planes, posv, lens, active, rw, dt):
+            """The MLA stack: the leading dense-FFN layers, then the scan
+            of the routed-expert layers; the planes ride in the carry and
+            each layer writes and reads its own index of them."""
+            B = x.shape[0]
+
+            def layer(h, bad, pl, at, lp):
+                with jax.named_scope("attn"):
+                    z = rms_norm(h, lp["ln1"], cfg.norm_eps,
+                                 ff_stats=policy.ff_reductions)
+                    q_nope, q_rope, c_new, kr_new = mla.mla_project_decode(
+                        lp["attn"], z, cfg, posv)
+                    if probe:
+                        bad = flag_nonfinite(bad, c_new, kr_new)
+                with jax.named_scope("kv"):
+                    c_kv = rw(pl, "c_kv", c_new[:, 0], (at,))
+                    k_rope = rw(pl, "k_rope", kr_new[:, 0], (at,))
+                with jax.named_scope("attn"):
+                    h = h + mla.mla_attend_latent(
+                        lp["attn"], q_nope, q_rope, c_kv, k_rope, lens + 1,
+                        cfg, dt, attn_impl=policy.attention)
+                routed = "router" in lp["ffn"]
+                with jax.named_scope("moe" if routed else "mlp"):
+                    z = rms_norm(h, lp["ln2"], cfg.norm_eps,
+                                 ff_stats=policy.ff_reductions)
+                    if routed:
+                        f, pairs = moe_serve(lp["ffn"], z, cfg,
+                                             ff_math=policy.ff_math,
+                                             valid=active)
+                    else:
+                        f = mlp_apply(lp["ffn"], z, ff_math=policy.ff_math)
+                        pairs = None
+                return h + f, bad, pl, pairs
+
+            bad = jnp.zeros((B,), jnp.bool_)
+            pl = dict(planes)
+            for name, first in layer_stacks(params, cfg):
+                if name == "dense_layers":
+                    for i in range(cfg.first_k_dense_replace):
+                        lp = jax.tree_util.tree_map(lambda w: w[i],
+                                                    params[name])
+                        x, bad, pl, _ = layer(x, bad, pl, i, lp)
+                    continue
+
+                def body(carry, scanned):
+                    h, bad, pl = carry
+                    lp, at = scanned
+                    h, bad, pl, pairs = layer(h, bad, pl, at, lp)
+                    return (h, bad, pl), pairs
+                n = jax.tree_util.tree_leaves(params[name])[0].shape[0]
+                (x, bad, pl), pairs = lax.scan(
+                    body, (x, bad, pl),
+                    (params[name], first + jnp.arange(n, dtype=jnp.int32)))
+            return x, bad, pl, pairs
 
         return step_decode
 
@@ -752,9 +852,8 @@ class ServeEngine:
         program is dispatched before the host reads the three in one
         ``device_get``."""
         logits, cache = self.prefill(prompt)
-        self.kv.write_prefill(slot, {
-            "k": cache["layers"]["k"][:, 0],
-            "v": cache["layers"]["v"][:, 0]})
+        self.kv.write_prefill(slot, {b: cache["layers"][b][:, 0]
+                                     for b in self.kv.bases})
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
         lp = self._score(logits, tok)
         lph, lpl = self._score_ff(logits, tok)
@@ -940,7 +1039,9 @@ class ServeEngine:
                     jnp.asarray(lens), jnp.asarray(self.kv.block_table),
                     jnp.asarray(active_np), self.kv.planes)
         with obs_mod.span(self.obs, "serve.decode_step"):
-            nxt, lp, lph, lpl, bad, self.kv.planes = self._decode(*args)
+            out = self._decode(*args)
+        nxt, lp, lph, lpl, bad, self.kv.planes = out[:6]
+        pairs = out[6] if len(out) > 6 else None
         if not self._decode_built:
             self._decode_built = True
             # the program just run, from jit's cache: no second compile
@@ -951,7 +1052,7 @@ class ServeEngine:
         self._token_dev = nxt
         self._pending.append({"step": self.decode_steps, "nxt": nxt,
                               "lp": lp, "lph": lph, "lpl": lpl,
-                              "bad": bad})
+                              "bad": bad, "pairs": pairs})
         self.decode_steps += 1
         for slot, state in enumerate(self._slots):
             if state is None:
@@ -976,12 +1077,14 @@ class ServeEngine:
         t0 = self.obs.trace.now()
         with obs_mod.span(self.obs, "serve.flush.wait"):
             host = jax.device_get([(e["nxt"], e["lp"], e["lph"], e["lpl"],
-                                    e["bad"]) for e in entries])
+                                    e["bad"], e["pairs"]) for e in entries])
         # host time blocked on the device (and the copies back)
         self.obs.registry.histogram("serve_flush_seconds").observe(
             (self.obs.trace.now() - t0) / 1e6)
         flagged: Dict[int, bool] = {}
-        for (e, (nxt, lp, lph, lpl, bad)) in zip(entries, host):
+        for (e, (nxt, lp, lph, lpl, bad, pairs)) in zip(entries, host):
+            if pairs is not None:
+                self._record_pairs(e["step"], np.asarray(pairs))
             nxt = np.asarray(nxt, np.int32)
             for slot, state in enumerate(self._slots):
                 if state is None or state["pending"] == 0:
@@ -1014,6 +1117,19 @@ class ServeEngine:
                 self._retire(slot)
         if self.guard_mode != "off":
             self._audit_paging()
+
+    def _record_pairs(self, step: int, pairs: np.ndarray) -> None:
+        """One decode step's (token, expert) pairs routed to each held
+        expert of each MoE layer, (L_moe, held): a ``moe`` counter event
+        (``pairs`` in all, ``hit`` the (layer, expert) cells with any,
+        ``max`` the fullest cell, and the ``layers`` and ``held`` it
+        spans) and the ``serve_moe_pairs_total`` counter."""
+        self.obs.trace.counter("moe", {
+            "step": step, "pairs": int(pairs.sum()),
+            "hit": int((pairs > 0).sum()), "max": int(pairs.max()),
+            "layers": pairs.shape[0], "held": pairs.shape[1]})
+        self.obs.registry.counter("serve_moe_pairs_total").inc(
+            int(pairs.sum()))
 
     def _must_flush(self) -> bool:
 
@@ -1398,7 +1514,7 @@ class ServeEngine:
         chaos-harness hook; the per-step probe only sees NEW K/V."""
         from repro.ff.guard import GuardCounts, guard_probe
         tot = [0, 0, 0]
-        for base in ("k", "v"):
+        for base in self.kv.bases:
             if self.kv.kv_mode == "ff_bf16":
                 plane = ff_merge(self.kv.planes[f"{base}_hi"],
                                  self.kv.planes[f"{base}_lo"])

@@ -2,10 +2,16 @@
 
 The contiguous per-sequence cache of ``models.init_cache`` wastes
 ``max_ctx`` slots per slot-holder; under continuous batching the live
-lengths are ragged and churn every few steps.  This module stores KV as
-``(L, num_pages, page_size, KV, hd)`` pools ("planes" — one per cached
-tensor) plus per-sequence page lists, so memory scales with the sum of
-live lengths rounded up to a page.
+lengths are ragged and churn every few steps.  This module stores the
+cache as ``(L, num_pages, page_size, ...)`` pools ("planes" — one per
+cached tensor) plus per-sequence page lists, so memory scales with the sum
+of live lengths rounded up to a page.
+
+Planes by attention kind: grouped-query attention caches ``k`` and ``v``,
+each ``(L, num_pages, page_size, KV, hd)``; latent attention (MLA) caches
+the ``c_kv`` latent ``(L, num_pages, page_size, kv_lora_rank)`` and the
+rotated ``k_rope`` ``(L, num_pages, page_size, rope_dim)``.  Both kinds
+share the block table, the free list and the page accounting.
 
 Float-float pages: in ``kv_mode="ff_bf16"`` each of k/v splits into an
 FF-style hi/lo limb pair (``hi = bf16(x)``, ``lo = bf16(x - hi)`` —
@@ -23,7 +29,7 @@ objects beyond the dict).
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 import jax.numpy as jnp
@@ -31,13 +37,16 @@ import jax.numpy as jnp
 Array = jnp.ndarray
 
 #: plane-name suffixes per kv_mode (all planes share the block table)
-_MODE_PLANES = {
-    "bf16": ("k", "v"),
-    "f32": ("k", "v"),
-    "ff_bf16": ("k_hi", "k_lo", "v_hi", "v_lo"),
-}
+_MODE_SUFFIXES = {"bf16": ("",), "f32": ("",), "ff_bf16": ("_hi", "_lo")}
 _MODE_DTYPE = {"bf16": jnp.bfloat16, "f32": jnp.float32,
                "ff_bf16": jnp.bfloat16}
+#: the cached tensors of each attention kind
+KIND_BASES = {"gqa": ("k", "v"), "mla": ("c_kv", "k_rope")}
+
+
+def _plane_names(kind: str, kv_mode: str):
+    return tuple(b + sfx for b in KIND_BASES[kind]
+                 for sfx in _MODE_SUFFIXES[kv_mode])
 
 
 def ff_split(x: Array, dtype=jnp.bfloat16):
@@ -59,34 +68,65 @@ def ff_merge(hi: Array, lo: Array) -> Array:
 class PagedKVCache:
     """Fixed-pool paged KV store for ``max_seqs`` concurrent sequences.
 
-    Planes are jnp arrays of shape ``(L, num_pages, page_size, KV, hd)``;
-    the block table is numpy ``(max_seqs, max_pages)`` int32 with ``-1``
-    marking unallocated entries.  Page 0..num_pages-1 are real; the engine
-    uses index ``num_pages`` as the out-of-bounds "drop" target for
-    inactive rows (``.at[...].set(mode="drop")``).
+    Planes are jnp arrays of shape ``(L, num_pages, page_size, *tail)``:
+    ``tail`` is ``(KV, hd)`` for the GQA planes and ``(r,)`` /
+    ``(rope_dim,)`` for MLA's (``latent=(r, rope_dim)``); the block table
+    is numpy ``(max_seqs, max_pages)`` int32 with ``-1`` marking
+    unallocated entries.  Page 0..num_pages-1 are real; the engine uses
+    index ``num_pages`` as the out-of-bounds "drop" target for inactive
+    rows (``.at[...].set(mode="drop")``).
     """
 
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int, *,
                  num_pages: int, page_size: int = 16, max_seqs: int = 8,
-                 max_ctx: int = 512, kv_mode: str = "bf16"):
-        if kv_mode not in _MODE_PLANES:
+                 max_ctx: int = 512, kv_mode: str = "bf16",
+                 latent: Optional[Tuple[int, int]] = None):
+        if kv_mode not in _MODE_SUFFIXES:
             raise ValueError(f"unknown kv_mode {kv_mode!r}; "
-                             f"choose from {tuple(_MODE_PLANES)}")
+                             f"choose from {tuple(_MODE_SUFFIXES)}")
         self.num_layers = num_layers
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
+        self.latent = None if latent is None else tuple(int(n)
+                                                        for n in latent)
+        self.kind = "gqa" if latent is None else "mla"
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_seqs = max_seqs
         self.max_pages = -(-max_ctx // page_size)   # pages per sequence row
         self.kv_mode = kv_mode
         dt = _MODE_DTYPE[kv_mode]
-        shape = (num_layers, num_pages, page_size, num_kv_heads, head_dim)
         self.planes: Dict[str, Array] = {
-            name: jnp.zeros(shape, dt) for name in _MODE_PLANES[kv_mode]}
+            name: jnp.zeros(self._shape(name), dt)
+            for name in _plane_names(self.kind, kv_mode)}
         self.block_table = np.full((max_seqs, self.max_pages), -1, np.int32)
         self.seq_lens = np.zeros((max_seqs,), np.int32)
         self.free_pages: List[int] = list(range(num_pages - 1, -1, -1))
+
+    @classmethod
+    def for_config(cls, cfg, **kw) -> "PagedKVCache":
+        """The planes a model config's attention caches: MLA's latent
+        pair when ``cfg.use_mla``, else GQA's K/V."""
+        latent = ((cfg.kv_lora_rank, cfg.qk_rope_head_dim) if cfg.use_mla
+                  else None)
+        return cls(cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim,
+                   latent=latent, **kw)
+
+    @property
+    def bases(self) -> Tuple[str, ...]:
+        """The cached tensors (``k``/``v`` or ``c_kv``/``k_rope``)."""
+        return KIND_BASES[self.kind]
+
+    def tail(self, base: str) -> Tuple[int, ...]:
+        """A cached tensor's per-position shape."""
+        if self.kind == "gqa":
+            return (self.num_kv_heads, self.head_dim)
+        return (self.latent[self.bases.index(base)],)
+
+    def _shape(self, name: str) -> Tuple[int, ...]:
+        base = next(b for b in self.bases if name.startswith(b))
+        return (self.num_layers, self.num_pages, self.page_size) \
+            + self.tail(base)
 
     # -- allocation --------------------------------------------------------
 
@@ -180,6 +220,13 @@ class PagedKVCache:
                 problems.append(f"slot {slot}: missing page below live "
                                 f"length {int(self.seq_lens[slot])}")
                 bad.add(slot)
+        for name in _plane_names(self.kind, self.kv_mode):
+            got = tuple(self.planes[name].shape) \
+                if name in self.planes else None
+            if got != self._shape(name):
+                problems.append(f"plane {name}: shape {got} != "
+                                f"{self._shape(name)}")
+                bad.update(range(self.max_seqs))
         return problems, bad
 
     def rebuild_free_list(self) -> None:
@@ -211,21 +258,23 @@ class PagedKVCache:
     # -- data movement -----------------------------------------------------
 
     def write_prefill(self, slot: int, tensors: Dict[str, Array]) -> None:
-        """Write per-layer contiguous K/V (``{"k": (L, S, KV, hd), "v":
-        ...}`` in compute f32/bf16) into this slot's pages.  In FF mode the
-        values are limb-split here; both limbs land in the same pages."""
-        S = int(tensors["k"].shape[1])
+        """Write per-layer contiguous cache tensors (GQA ``{"k": (L, S, KV,
+        hd), "v": ...}``, MLA ``{"c_kv": (L, S, r), "k_rope": (L, S,
+        rope_dim)}``, in compute f32/bf16) into this slot's pages.  In FF
+        mode the values are limb-split here; both limbs land in the same
+        pages."""
+        S = int(tensors[self.bases[0]].shape[1])
         if S != int(self.seq_lens[slot]):
             raise ValueError("prefill length != allocated length")
         npg = self.pages_for(S)
         ids = self.block_table[slot, :npg]
         pad = npg * self.page_size - S
-        for base in ("k", "v"):
+        for base in self.bases:
             x = jnp.asarray(tensors[base])
             if pad:
-                x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            paged = x.reshape(x.shape[0], npg, self.page_size,
-                              self.num_kv_heads, self.head_dim)
+                x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            paged = x.reshape((x.shape[0], npg, self.page_size)
+                              + self.tail(base))
             if self.kv_mode == "ff_bf16":
                 hi, lo = ff_split(paged)
                 self.planes[f"{base}_hi"] = \
@@ -238,22 +287,23 @@ class PagedKVCache:
                     self.planes[base].at[:, ids].set(paged.astype(dt))
 
     def gather(self, slot: int) -> Dict[str, Array]:
-        """Contiguous read-back of a slot ({"k": (L, S, KV, hd), ...}, f32
+        """Contiguous read-back of a slot (``{base: (L, S, *tail)}``, f32
         in FF mode, storage dtype otherwise).  Host/debug path — the engine
         gathers on-device inside its jitted step instead."""
         S = int(self.seq_lens[slot])
         npg = self.pages_for(S)
         ids = self.block_table[slot, :npg]
         out = {}
-        for base in ("k", "v"):
+        for base in self.bases:
             if self.kv_mode == "ff_bf16":
                 hi = self.planes[f"{base}_hi"][:, ids]
                 lo = self.planes[f"{base}_lo"][:, ids]
                 paged = ff_merge(hi, lo)
             else:
                 paged = self.planes[base][:, ids]
-            out[base] = paged.reshape(self.num_layers, npg * self.page_size,
-                                      self.num_kv_heads, self.head_dim)[:, :S]
+            out[base] = paged.reshape(
+                (self.num_layers, npg * self.page_size)
+                + self.tail(base))[:, :S]
         return out
 
     # -- serialization -----------------------------------------------------
@@ -261,7 +311,9 @@ class PagedKVCache:
     def to_state(self) -> Dict[str, np.ndarray]:
         """Whole cache as a flat dict of numpy arrays (plus scalars of the
         geometry).  bf16 planes ship as uint16 bit patterns so the dict
-        round-trips through any numpy-only container (npz, plasma, ...)."""
+        round-trips through any numpy-only container (npz, plasma, ...).
+        MLA's adds ``latent`` (r, rope_dim); GQA's holds what it always
+        held."""
         state: Dict[str, np.ndarray] = {
             "block_table": self.block_table.copy(),
             "seq_lens": self.seq_lens.copy(),
@@ -273,6 +325,8 @@ class PagedKVCache:
             "kv_mode": np.frombuffer(
                 self.kv_mode.encode().ljust(8, b"\0"), np.uint8).copy(),
         }
+        if self.latent is not None:
+            state["latent"] = np.asarray(self.latent, np.int64)
         for name, plane in self.planes.items():
             arr = np.asarray(plane)
             if arr.dtype == jnp.bfloat16:
@@ -297,25 +351,31 @@ class PagedKVCache:
                              f"entries; expected 7")
         L, KV, hd, NP, ps, ms, mc = (int(v) for v in geom)
         mode = bytes(state["kv_mode"]).rstrip(b"\0").decode()
-        if mode not in _MODE_PLANES:
+        if mode not in _MODE_SUFFIXES:
             raise ValueError(f"KV state names unknown kv_mode {mode!r}")
-        want_shape = (L, NP, ps, KV, hd)
-        for name in _MODE_PLANES[mode]:
+        latent = None
+        if "latent" in state:
+            latent = tuple(int(v) for v in np.asarray(state["latent"]).ravel())
+            if len(latent) != 2:
+                raise ValueError(f"KV state latent has {len(latent)} "
+                                 f"entries; expected 2")
+        self = cls(L, KV, hd, num_pages=NP, page_size=ps, max_seqs=ms,
+                   max_ctx=mc, kv_mode=mode, latent=latent)
+        names = _plane_names(self.kind, mode)
+        for name in names:
             key = f"plane_{name}"
             if key not in state:
                 raise ValueError(f"KV state missing plane {key!r} for "
                                  f"kv_mode {mode!r}")
             got = tuple(np.asarray(state[key]).shape)
-            if got != want_shape:
+            if got != self._shape(name):
                 raise ValueError(f"KV state plane {key!r} shape {got} != "
-                                 f"geometry {want_shape}")
-        self = cls(L, KV, hd, num_pages=NP, page_size=ps, max_seqs=ms,
-                   max_ctx=mc, kv_mode=mode)
+                                 f"geometry {self._shape(name)}")
         self.block_table = np.asarray(state["block_table"], np.int32).copy()
         self.seq_lens = np.asarray(state["seq_lens"], np.int32).copy()
         self.free_pages = [int(p) for p in state["free_pages"]]
         dt = _MODE_DTYPE[mode]
-        for name in _MODE_PLANES[mode]:
+        for name in names:
             arr = state[f"plane_{name}"]
             if dt == jnp.bfloat16:
                 arr = arr.view(np.uint16)
